@@ -1,26 +1,20 @@
 """Kernel-level benchmark: tuned vs default launch geometry per
-(format, op), and the native row-segmented CSR kernel vs the old
-CSR-via-COO detour.
+(format, op).
 
 Matrices are chosen per format the way the paper's auto-tuner would route
 them: CSR is benched on torso1 — the suite's flagship heavy-tail matrix
 (D_mat 5.72), exactly the kind the D_mat–R rule keeps in CRS (the paper
 removed torso1's ELL run for memory overflow) — while the regular,
-transform-friendly chem_master1 carries the ELL/SELL/COO/BCSR rows.
+transform-friendly chem_master1 carries the ELL/SELL rows.
 
 Every (format, op) pair runs through ``core.kernel_tune.KernelTuner`` —
 the default launch is always one of the timed candidates, so the reported
 ``tuned_speedup = t_default / t_best`` is >= 1.0 by construction (equality
-means the default was already the winner).  The CSR rows additionally time
-``ops.spmv_csr_via_coo`` (the pre-native path, at the geometry it shipped
-with) head-to-head against the tuned native kernel, interleaving the two
-and taking per-path minima so scheduler drift cancels; ``native_vs_coo``
-is that ratio.
+means the default was already the winner).
 
-Interpret-mode caveat: off-TPU the Pallas kernels execute in the
-interpreter, so absolute times are not TPU numbers — the *relative*
-geometry ranking and the regression-guard properties (tuned >= default,
-native CSR SpMV > detour) are what the CI smoke step checks.
+The kernels run compiled on a TPU and in the Pallas interpreter elsewhere
+(``kernels.ops`` chooses by backend); off the chip the times describe the
+interpreter, not a device.
 
     PYTHONPATH=src python -m benchmarks.kernels_bench [--quick]
         [--scale S] [--iters N] [--json OUT.json]
@@ -29,8 +23,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,34 +37,11 @@ from repro.kernels import ops, ref
 from .common import Row
 
 # matrix -> formats benched on it (formats where the D_mat–R rule would
-# actually land that matrix; see module docstring).  ccs rides with csr on
-# the heavy-tail matrix: the paper's Phase-I product is exactly what a
-# CRS-bound matrix transforms to when column structure is the regular one.
+# actually land that matrix; see module docstring)
 BENCH_PLAN: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
-    ("torso1", ("csr", "ccs", "coo_row")),
-    ("chem_master1", ("ell_row", "sell", "coo_row", "bcsr")),
+    ("torso1", ("csr",)),
+    ("chem_master1", ("ell_row", "sell")),
 )
-LEGACY_BASELINES: Dict[Tuple[str, str], Callable] = {
-    ("csr", "spmv"): ops.spmv_csr_via_coo,
-    ("csr", "spmm"): ops.spmm_csr_via_coo,
-}
-
-
-def _interleaved(fa: Callable[[], None], fb: Callable[[], None],
-                 iters: int) -> Tuple[float, float]:
-    """Per-path best-of with A/B interleaving — slow drift (GC, noisy
-    neighbours) hits both paths equally instead of whichever ran second."""
-    fa()
-    fb()
-    ta = tb = float("inf")
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        fa()
-        ta = min(ta, time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        fb()
-        tb = min(tb, time.perf_counter() - t0)
-    return ta, tb
 
 
 def run(scale: float = 0.01, iters: int = 3, batch: int = 8,
@@ -79,7 +49,7 @@ def run(scale: float = 0.01, iters: int = 3, batch: int = 8,
     plan = plan or BENCH_PLAN
     suite = dict(paper_suite(scale=scale,
                              include=[name for name, _ in plan]))
-    tuner = KernelTuner(interpret=True, iters=iters, warmup=1)
+    tuner = KernelTuner(iters=iters, warmup=1)
     rows: List[Row] = []
     for mat_name, formats in plan:
         csr = suite[mat_name]
@@ -104,18 +74,6 @@ def run(scale: float = 0.01, iters: int = 3, batch: int = 8,
                 }
                 if op == "spmm":
                     derived["batch"] = batch
-                base = LEGACY_BASELINES.get((fmt, op))
-                if base is not None:
-                    jb = jax.jit(lambda m, v, _f=base:
-                                 _f(m, v, interpret=True))
-                    jn = jax.jit(lambda m, v, _f=impl, _g=rec.geometry:
-                                 _f(m, v, interpret=True, tuning=_g))
-                    t_coo, t_native = _interleaved(
-                        lambda: jax.block_until_ready(jb(obj, x)),
-                        lambda: jax.block_until_ready(jn(obj, x)),
-                        max(iters, 6))
-                    derived["t_via_coo_us"] = f"{t_coo * 1e6:.1f}"
-                    derived["native_vs_coo"] = f"{t_coo / t_native:.3f}"
                 rows.append(Row(name=f"kernels/{fmt}_{op}/{mat_name}",
                                 us_per_call=rec.t_best * 1e6,
                                 derived=derived))
@@ -126,7 +84,7 @@ def run(scale: float = 0.01, iters: int = 3, batch: int = 8,
             x1 = jnp.ones((csr.n_cols,), jnp.float32)
             d, c = jnp.asarray(ell.data), jnp.asarray(ell.cols)
             err = float(jnp.max(jnp.abs(
-                ops.ell_spmv_raw(d, c, x1, interpret=True) -
+                ops.ell_spmv_raw(d, c, x1) -
                 ref.ell_spmv_ref(d, c, x1))))
             t_ref = time_fn(jax.jit(spmv), ell, x1, iters=iters)
             rows.append(Row(name=f"kernels/ell_ref/{mat_name}",
@@ -147,8 +105,8 @@ def main() -> None:
     args = ap.parse_args()
     scale = args.scale if args.scale is not None else 0.01
     iters = args.iters if args.iters is not None else (1 if args.quick else 3)
-    plan = (("torso1", ("csr", "ccs")),
-            ("chem_master1", ("ell_row", "coo_row"))) if args.quick else None
+    plan = (("torso1", ("csr",)),
+            ("chem_master1", ("ell_row",))) if args.quick else None
     rows = run(scale=scale, iters=iters, batch=args.batch, plan=plan)
     from .common import print_rows
     print_rows(rows)

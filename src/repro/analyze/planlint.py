@@ -22,10 +22,8 @@ Rule catalog (docs/analysis.md):
 
   RPL001  schema shape: required/unknown fields, types, schema_version
   RPL002  TileGeometry: unknown knobs, positivity, 8-alignment
-          (BCSR block-row tiles may legitimately clamp below 8 -> WARN)
-  RPL003  slab-coverage bound vs the static lower bound implied by the
-          recorded fingerprint (CSR/BCSR; CCS has no column count to
-          bound against)
+  RPL003  CSR slab-coverage bound vs the static lower bound implied by
+          the recorded fingerprint
   RPL004  per-(format, op) geometry-driven VMEM footprint vs budget
   RPL005  SELL bucket table vs the transform recipe (width quantum,
           duplicate widths, bucket count vs slice_rows)
@@ -44,48 +42,16 @@ from __future__ import annotations
 
 import hashlib
 import json
-import sys
 from typing import Any, Dict, List, Optional
 
 from .findings import ERROR, WARN, Finding
 
-#: default ceiling for the geometry-driven VMEM working set (RPL004).
-#: Most TPU cores have ~16 MiB of VMEM; the model below deliberately
-#: counts only the knob-driven tiles (see docs/analysis.md), so a plan
-#: over this budget cannot fit regardless of the matrix it binds.
+#: ceiling for the geometry-driven VMEM working set (RPL004): the
+#: compiler's default scoped-VMEM limit on a TPU v5e core, which is the
+#: limit every kernel in ``repro.kernels`` compiles under (none raises
+#: it).  ``tests/test_chip_compile.py`` compiles every tuner candidate
+#: for a described v5e against that limit.
 DEFAULT_VMEM_BUDGET = 16 * 2 ** 20
-
-#: VMEM ceiling for TPU generations with larger on-chip provisioning
-#: (v4 and later parts); only used when the running process can prove
-#: it is on one (see :func:`default_vmem_budget`)
-LARGE_VMEM_BUDGET = 128 * 2 ** 20
-
-
-def default_vmem_budget() -> int:
-    """The RPL004 budget for *this* process's backend.
-
-    This module must stay importable (and linting) without jax — the CLI
-    and ``PlanStore`` sweeps run jax-free — so jax is never imported
-    here; it is only *queried* when something else already imported it
-    (``sys.modules``).  Without jax, or on cpu/gpu backends, or on any
-    TPU generation this heuristic does not recognize, the conservative
-    16 MiB core budget applies; known v4+ TPU device kinds get the
-    larger provisioning.  ``lint_plan(vmem_budget=...)`` always wins
-    over this default."""
-    jax_mod = sys.modules.get("jax")
-    if jax_mod is None:
-        return DEFAULT_VMEM_BUDGET
-    try:
-        dev = jax_mod.devices()[0]
-        if getattr(dev, "platform", "") != "tpu":
-            return DEFAULT_VMEM_BUDGET
-        kind = str(getattr(dev, "device_kind", "")).lower()
-    except (RuntimeError, IndexError, AttributeError, ValueError):
-        # backend init failure must read as "unknown", not crash a lint
-        return DEFAULT_VMEM_BUDGET
-    if any(gen in kind for gen in ("v4", "v5", "v6", "v7")):
-        return LARGE_VMEM_BUDGET
-    return DEFAULT_VMEM_BUDGET
 
 #: mirrors core.plan.SCHEMA_VERSION / SHARDED_SCHEMA_VERSION (the
 #: registry audit's job is to notice if these ever drift)
@@ -103,20 +69,18 @@ KNOWN_TIERS = ("reference", "kernel")
 
 GEOM_KNOBS = ("block_rows", "block_w", "block_k", "block_nnz",
               "slabs_per_block")
-#: knobs each format's kernel wrappers actually read (kernels/ops.py)
+#: knobs each format's kernel wrappers actually read (kernels/ops.py);
+#: formats without a kernel read none
 _FMT_KNOBS = {
     "ell_row": {"block_rows", "block_w", "block_k"},
     "ell_col": {"block_rows", "block_w", "block_k"},
     "sell": {"block_rows", "block_w", "block_k"},
-    "coo_row": {"block_nnz", "block_k"},
-    "coo_col": {"block_nnz", "block_k"},
     "csr": {"block_rows", "block_nnz", "block_k", "slabs_per_block"},
-    "ccs": {"block_rows", "block_nnz", "block_k", "slabs_per_block"},
-    "bcsr": {"block_rows", "block_nnz", "block_k", "slabs_per_block"},
+    "coo_row": set(), "coo_col": set(), "ccs": set(), "bcsr": set(),
 }
 #: wrapper defaults used when a knob is absent (kernels/ops.py)
-_DEFAULT_BR = {"bcsr": 32}          # others: 256
-_DEFAULT_BN = {"bcsr": 512}         # others: 2048
+_DEFAULT_BR = 256
+_DEFAULT_BN = 2048
 _DEFAULT_BW = 128
 _DEFAULT_BK = 128
 
@@ -224,14 +188,7 @@ class _Lint:
                          f"{k}={v!r} must be a positive integer")
                 continue
             if k != "slabs_per_block" and v % 8:
-                if fmt == "bcsr" and k == "block_rows":
-                    # the BCSR grid clamps row tiles to the block-row
-                    # count, which may legitimately fall below 8
-                    self.warn("RPL002", where,
-                              f"{k}={v} is not 8-aligned (BCSR block-row "
-                              f"tiles may clamp below the lane width)")
-                else:
-                    self.err("RPL002", where, f"{k}={v} is not 8-aligned")
+                self.err("RPL002", where, f"{k}={v} is not 8-aligned")
             if k not in relevant:
                 self.warn("RPL002", where,
                           f"{k} is not used by the {fmt!r} kernels")
@@ -286,9 +243,7 @@ class _Lint:
                     fp: Optional[Dict[str, Any]],
                     params: Dict[str, Any]) -> None:
         spb = gd.get("slabs_per_block")
-        if not _is_int(spb) or fmt not in ("csr", "bcsr"):
-            # CCS segments columns; the fingerprint has no column count
-            # to bound against
+        if not _is_int(spb) or fmt != "csr":
             return
         if fp is None:
             self.warn("RPL003", where, "slabs_per_block recorded but the "
@@ -296,18 +251,12 @@ class _Lint:
                                        "it against")
             return
         n, nnz = fp["n"], fp["nnz"]
-        br = gd.get("block_rows") or _DEFAULT_BR.get(fmt, 256)
-        bn = gd.get("block_nnz") or _DEFAULT_BN.get(fmt, 2048)
+        br = gd.get("block_rows") or _DEFAULT_BR
+        bn = gd.get("block_nnz") or _DEFAULT_BN
         if not _is_int(br) or not _is_int(bn) or br < 1 or bn < 1:
             return                      # RPL002 already reported
-        if fmt == "bcsr":
-            b = params.get("block")
-            b = b if _is_int(b) and b >= 1 else 8
-            segments = _ceil(_ceil(n, b), br)    # block-row tiles
-            units = _ceil(nnz, b * b)            # >= stored blocks
-        else:
-            segments = _ceil(n, br)              # row tiles
-            units = nnz
+        segments = _ceil(n, br)                  # row tiles
+        units = nnz
         # every launch sweeps segments * spb slabs of bn units each; the
         # recorded structure needs at least ceil(units / (segments * bn))
         # slabs per segment block no matter how the rows distribute
@@ -835,11 +784,14 @@ def _footprint(gd: Dict[str, Any], fmt: str, op: str,
         if bk is None:
             return None
         k = bk
+    k8 = _align8(k)                 # sublane-padded right-hand-side rows
     if fmt in ("ell_row", "ell_col", "sell"):
-        br, bw = knob("block_rows", 256), knob("block_w", _DEFAULT_BW)
+        br, bw = knob("block_rows", _DEFAULT_BR), knob("block_w", _DEFAULT_BW)
         if br is None or bw is None:
             return None
-        size = br * bw * 8 + bw * k * 4 + br * k * 4
+        # double-buffered VAL (bw, br), gathered-x panel (bw, k, br) and
+        # output (k, br) tiles
+        size = 2 * 4 * (bw * br + bw * k * br + k8 * br)
         buckets = gd.get("buckets")
         if fmt == "sell" and isinstance(buckets, list):
             for pair in buckets:
@@ -849,23 +801,15 @@ def _footprint(gd: Dict[str, Any], fmt: str, op: str,
                     if sub is not None:
                         size = max(size, sub)
         return size
-    if fmt in ("coo_row", "coo_col"):
-        bn = knob("block_nnz", 65536)
-        return None if bn is None else bn * 12 + k * 4
-    if fmt in ("csr", "ccs"):
-        br = knob("block_rows", _DEFAULT_BR.get(fmt, 256))
-        bn = knob("block_nnz", _DEFAULT_BN.get(fmt, 2048))
+    if fmt == "csr":
+        br = knob("block_rows", _DEFAULT_BR)
+        bn = knob("block_nnz", _DEFAULT_BN)
         if br is None or bn is None:
             return None
-        return bn * 8 + (br + 1) * 4 + br * k * 4
-    if fmt == "bcsr":
-        b = params.get("block")
-        b = b if _is_int(b) and b >= 1 else 8
-        br = knob("block_rows", _DEFAULT_BR["bcsr"])
-        bn = knob("block_nnz", _DEFAULT_BN["bcsr"])
-        if br is None or bn is None:
-            return None
-        return bn * (b * b * 4 + 4) + (br + 1) * 4 + br * b * k * 4
+        # double-buffered row-pointer rows, VAL slab, gathered-x panel and
+        # output tiles, plus the (bn, br) one-hot and its compare mask
+        return 2 * 4 * (16 * br + 8 * bn + k8 * bn + k8 * br) \
+            + 2 * 4 * bn * br
     return None
 
 
@@ -877,10 +821,9 @@ def lint_plan(payload: Any,
     """Lint a plan payload dict — ExecutionPlan, ShardedPlan, or a
     streaming artifact (``delta_batch`` / ``stream_plan``), routed on
     ``kind``.  Returns findings; empty means clean.  ``vmem_budget``
-    defaults to :func:`default_vmem_budget` — the running backend's
-    provisioning when knowable, 16 MiB otherwise."""
+    defaults to :data:`DEFAULT_VMEM_BUDGET`."""
     lint = _Lint(vmem_budget if vmem_budget is not None
-                 else default_vmem_budget())
+                 else DEFAULT_VMEM_BUDGET)
     if not isinstance(payload, dict):
         lint.err("RPL001", "plan", f"plan payload must be a JSON object; "
                                    f"got {type(payload).__name__}")
@@ -936,7 +879,7 @@ def lint_text(text: str,
     return lint_plan(obj, vmem_budget=vmem_budget)
 
 
-__all__ = ["DEFAULT_VMEM_BUDGET", "LARGE_VMEM_BUDGET", "KNOWN_FORMATS",
+__all__ = ["DEFAULT_VMEM_BUDGET", "KNOWN_FORMATS",
            "KNOWN_OPS", "KNOWN_TIERS", "GEOM_KNOBS",
-           "default_vmem_budget", "lint_plan", "lint_envelope",
+           "lint_plan", "lint_envelope",
            "lint_text"]

@@ -386,20 +386,58 @@ def decide_generalized(db: TuningDB, stats: MatrixStats,
 # ---------------------------------------------------------------------------
 # measurement-free roofline cost model (beyond paper)
 # ---------------------------------------------------------------------------
+#: memory bandwidth per device, keyed by ``jax.Device.device_kind``, with
+#: its source.  A device kind missing here is an error, never a default.
+DEVICE_BANDWIDTH: Dict[str, Tuple[float, str]] = {
+    "TPU v5 lite": (819e9, "Google Cloud TPU v5e documentation: "
+                           "16 GB of HBM at 819 GB/s per chip"),
+    # The host CPU has no published figure; the cost model's decisions
+    # depend only on bandwidth ratios (every term scales with
+    # 1 / stream_bw), so CPU runs use this nominal unit and report no
+    # device time from it.
+    "cpu": (819e9, "nominal: decisions on a CPU depend only on ratios"),
+}
+
+
+def device_bandwidth(kind: Optional[str] = None) -> float:
+    """Memory bandwidth (bytes/s) of ``kind``, default the first device
+    JAX reports (its ``device_kind``; the platform name for CPUs)."""
+    if kind is None:
+        dev = jax.devices()[0]
+        kind = "cpu" if dev.platform == "cpu" else dev.device_kind
+    if kind not in DEVICE_BANDWIDTH:
+        raise KeyError(f"no memory bandwidth recorded for device kind "
+                       f"{kind!r}; add it to DEVICE_BANDWIDTH with its "
+                       f"source")
+    return DEVICE_BANDWIDTH[kind][0]
+
+
 @dataclass
 class MachineModel:
     """Bandwidth/latency model used to pre-seed decisions on a new machine.
+
+    ``stream_bw`` defaults to the running device's entry in
+    :data:`DEVICE_BANDWIDTH`; the random-gather bandwidth is modelled as
+    ``gather_fraction`` of it.
 
     ``segment_penalty`` models the segmented-reduction inefficiency of
     CSR/COO on vector hardware: the effective vector length is the row
     length (~mu, tiny), while ELL reduces dense (rows, width) panels at
     full lane width — the mechanism behind the paper's 151x ES2 result,
     and equally behind the TPU VPU's preference for ELL."""
-    stream_bw: float = 819e9      # bytes/s contiguous (TPU v5e HBM)
-    gather_bw: float = 819e9 / 8  # bytes/s random-gather effective
+    stream_bw: Optional[float] = None   # bytes/s contiguous
+    gather_fraction: float = 1 / 8      # random-gather share of stream_bw
     val_bytes: int = 4
     idx_bytes: int = 4
     segment_penalty: float = 3.0  # CSR/COO segmented-reduce inefficiency
+
+    def __post_init__(self):
+        if self.stream_bw is None:
+            self.stream_bw = device_bandwidth()
+
+    @property
+    def gather_bw(self) -> float:
+        return self.stream_bw * self.gather_fraction
 
     def t_spmv(self, fmt: str, stats: MatrixStats,
                width: Optional[int] = None, batch: int = 1) -> float:
@@ -512,5 +550,6 @@ __all__ = [
     "DEFAULT_FORMATS", "time_fn", "time_host",
     "FormatMeasurement", "OfflineRecord", "TuningDB",
     "offline_phase", "Decision", "decide_paper", "decide_generalized",
-    "MachineModel", "decide_cost_model", "AutoTunedSpMV",
+    "DEVICE_BANDWIDTH", "device_bandwidth", "MachineModel",
+    "decide_cost_model", "AutoTunedSpMV",
 ]

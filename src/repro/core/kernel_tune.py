@@ -11,12 +11,13 @@ argument for our stack:
 
   * :class:`TileGeometry` — the knobs every kernel wrapper in
     ``kernels/ops.py`` accepts per call (``tuning=``): ``block_rows`` /
-    ``block_w`` (ELL band tiles, BCSR row tiles), ``block_k`` (SpMM RHS
-    tile), ``block_nnz`` (COO/CSR nnz slab) and ``slabs_per_block`` (the
-    CSR/BCSR static slab-coverage bound — data-dependent, so only the
-    tuner, holding the concrete matrix, can supply it to traced callers);
+    ``block_w`` (ELL band tiles), ``block_k`` (SpMM RHS tile),
+    ``block_nnz`` (CSR nnz slab) and ``slabs_per_block`` (the CSR static
+    slab-coverage bound — data-dependent, so only the tuner, holding the
+    concrete matrix, can supply it to traced callers);
   * :func:`candidate_geometries` — the bounded per-(format, op) search
-    grid (``block_rows in {8..512}``, ``block_w in {8,128,256}``, ...);
+    grid, holding only tiles the TPU compiler accepts
+    (``tests/test_chip_compile.py`` compiles every candidate for v5e);
   * :class:`KernelTuner` — times real launches per candidate, memoizes the
     winner per ``(format, op, batch, matrix profile)``, records into the
     existing :class:`~repro.core.autotune.TuningDB` (persisted next to the
@@ -38,10 +39,10 @@ import numpy as np
 import repro.obs as _obs
 
 from . import dispatch as _dispatch
-from .formats import CCS, CSR, MatrixStats
+from .formats import CSR, MatrixStats
 
 __all__ = [
-    "TileGeometry", "GeometryRecord", "GRID_FORMATS",
+    "TileGeometry", "GeometryRecord", "GRID_FORMATS", "MAX_TILE",
     "candidate_geometries", "nearest_geometry", "KernelTuner",
 ]
 
@@ -54,20 +55,18 @@ class TileGeometry:
     """Per-call launch geometry; ``None`` fields fall back to the wrapper's
     built-in default.  Hashable so it can ride through static closures.
 
-    ``block_rows`` is the *segmented-axis* tile: rows for ELL/CSR (and
-    block rows for BCSR), columns for CCS — one knob, because no kernel
-    tiles both axes independently.
+    ``block_rows`` is the row tile of the ELL and CSR kernels.
 
     ``buckets`` is the SELL per-bucket table: ``((width, TileGeometry),
     ...)`` pairs keyed by bucket *width*, so one persisted geometry carries
     a different tile shape for every bucket of the container (SELL-C-σ's
     point: chunk geometry is per-chunk).  Bucket widths absent from the
     table fall back to the top-level knobs."""
-    block_rows: Optional[int] = None   # ELL/CSR row tile; CCS col tile; BCSR block-row tile
-    block_w: Optional[int] = None      # ELL band (lane) tile
+    block_rows: Optional[int] = None   # ELL/CSR row (lane) tile
+    block_w: Optional[int] = None      # ELL band (sublane) tile
     block_k: Optional[int] = None      # SpMM right-hand-side tile
-    block_nnz: Optional[int] = None    # COO/CSR/CCS nnz slab; BCSR blocks/slab
-    slabs_per_block: Optional[int] = None  # CSR/CCS/BCSR static coverage bound
+    block_nnz: Optional[int] = None    # CSR nnz slab
+    slabs_per_block: Optional[int] = None  # CSR static coverage bound
     buckets: Optional[Tuple[Tuple[int, "TileGeometry"], ...]] = None  # SELL
 
     _KNOBS = ("block_rows", "block_w", "block_k", "block_nnz",
@@ -98,8 +97,8 @@ class TileGeometry:
     def without_slab_bound(self) -> "TileGeometry":
         """Strip the data-dependent coverage bound — required when a
         geometry learned on one matrix is applied to another under trace
-        (the bound would silently drop entries; without it the CSR/CCS/BCSR
-        kernels fall back to the always-correct full sweep, and concrete
+        (the bound would silently drop entries; without it the CSR kernel
+        falls back to the always-correct full sweep, and concrete
         callers recompute the exact bound anyway).  Applies through the
         per-bucket table too."""
         buckets = self.buckets
@@ -155,39 +154,31 @@ class GeometryRecord:
 # ---------------------------------------------------------------------------
 # the bounded search grid
 # ---------------------------------------------------------------------------
-ROW_TILES = (8, 32, 128, 256, 512)
-W_TILES = (8, 128, 256)
-K_TILES = (8, 128)
-NNZ_TILES = (1024, 4096, 16384, 65536)
-CSR_ROW_TILES = (64, 128, 256, 512)
-CSR_NNZ_TILES = (1024, 4096, 16384, 65536)
-BCSR_ROW_TILES = (8, 32, 64)
-BCSR_NNZ_TILES = (128, 512, 2048)
-# the whole-nnz "one slab" boundary candidate is capped so a slab's VAL +
-# ICOL stay ~2 MiB — comfortably inside VMEM next to the pinned x
-MAX_SLAB = 262144
-MAX_BLOCK_SLAB = 8192
+# Every tile below is one the TPU compiler accepts for its axis: row
+# tiles lie along the 128 vector lanes (a multiple of 128, or the whole
+# axis once clamped to a smaller matrix); band and right-hand-side tiles
+# lie along the sublanes (multiples of 8).
+ROW_TILES = (128, 256, 512)
+W_TILES = (8, 32, 128)
+K_TILES = (8, 32, 128)
+CSR_ROW_TILES = (128, 256, 512)
+CSR_NNZ_TILES = (512, 1024, 2048, 4096)
+#: cap on the largest per-step tile, in elements: the CSR kernel's
+#: (block_nnz, block_rows) one-hot row-recovery tile, and the ELL SpMM
+#: kernel's (block_w, block_k, block_rows) gathered-x panel.  It keeps
+#: every candidate inside the compiler's default scoped VMEM.
+MAX_TILE = 1 << 20
 
 #: every format with a bounded candidate grid below — the kernel tier's
 #: tunable surface.  Kept as a plain literal tuple so the static registry
 #: audit (``repro.analyze``) can read it without importing jax; the
 #: ``candidate_geometries`` gate uses it, so a kernel registered without a
 #: grid entry is caught both here and by the audit.
-GRID_FORMATS = ("ell_row", "ell_col", "sell", "coo_row", "coo_col",
-                "csr", "ccs", "bcsr")
+GRID_FORMATS = ("ell_row", "ell_col", "sell", "csr")
 
 
 def _align8(n: int) -> int:
     return max(8, 8 * ((int(n) + 7) // 8))
-
-
-def _nnz_tiles(base, nnz_pad: int, cap: int):
-    """Slab-size candidates: the base grid clamped to the matrix, plus the
-    whole-nnz single-slab boundary (itself clamped to the VMEM cap)."""
-    if not nnz_pad:
-        return sorted(base)
-    whole = min(_align8(nnz_pad), cap)
-    return sorted({min(bn, whole) for bn in base} | {whole})
 
 
 def candidate_geometries(fmt: str, op: str = "spmv", *, n_rows: int = 0,
@@ -211,36 +202,24 @@ def candidate_geometries(fmt: str, op: str = "spmv", *, n_rows: int = 0,
         for r in sorted(rows):
             for w in sorted(ws):
                 for k in ks:
+                    if r * w * (k or 1) > MAX_TILE:
+                        continue
                     geoms.append(TileGeometry(block_rows=r, block_w=w,
                                               block_k=k))
-    elif fmt.startswith("coo"):
-        for bn in _nnz_tiles(NNZ_TILES, nnz_pad, MAX_SLAB):
-            for k in ks:
-                geoms.append(TileGeometry(block_nnz=bn, block_k=k))
-    elif fmt in ("csr", "ccs"):
-        # same segmented-slab grid for both; ``n_rows`` is the segmented
-        # axis length, so CCS callers pass the *column* count
+    else:
+        # CSR: both tiles lie along lanes; a whole-axis tile (a matrix
+        # smaller than the tile) is clamped to the 8-aligned extent
         rows = {min(r, _align8(n_rows)) for r in CSR_ROW_TILES} if n_rows \
             else set(CSR_ROW_TILES)
-        if n_rows:
-            # the single-segment-block boundary (tile capped for VMEM)
-            rows.add(min(_align8(n_rows), MAX_SLAB))
-        for r in sorted(rows):
-            for bn in _nnz_tiles(CSR_NNZ_TILES, nnz_pad, MAX_SLAB):
-                for k in ks:
-                    geoms.append(TileGeometry(block_rows=r, block_nnz=bn,
-                                              block_k=k))
-    elif fmt == "bcsr":
-        rows = {min(r, max(1, n_rows)) for r in BCSR_ROW_TILES} if n_rows \
-            else set(BCSR_ROW_TILES)
-        bns = set(_nnz_tiles(BCSR_NNZ_TILES, nnz_pad, MAX_BLOCK_SLAB))
+        bns = {min(bn, _align8(nnz_pad)) for bn in CSR_NNZ_TILES} \
+            if nnz_pad else set(CSR_NNZ_TILES)
         for r in sorted(rows):
             for bn in sorted(bns):
+                if r * bn > MAX_TILE:
+                    continue
                 for k in ks:
                     geoms.append(TileGeometry(block_rows=r, block_nnz=bn,
                                               block_k=k))
-    else:
-        return []
     seen, out = set(), []
     for g in geoms:
         key = (g.block_rows, g.block_w, g.block_k, g.block_nnz)
@@ -297,16 +276,9 @@ def _profile_of(obj: Any, stats: Optional[MatrixStats] = None
     n = int(getattr(obj, "n_rows", 0))
     nnz = int(getattr(obj, "nnz", 0))
     d_mat = 0.0
-    ip = getattr(obj, "indptr", None)
-    if ip is not None and not isinstance(ip, jax.core.Tracer):
-        if isinstance(obj, CSR):
-            d_mat = float(MatrixStats.of(obj).d_mat)
-        elif isinstance(obj, CCS):
-            # the column-space analogue: nnz-per-column variation is what
-            # shapes the column-segmented launch
-            lens = np.diff(np.asarray(ip)).astype(np.float64)
-            mu = float(lens.mean()) if lens.size else 0.0
-            d_mat = float(lens.std() / mu) if mu > 0 else 0.0
+    if isinstance(obj, CSR) and not isinstance(obj.indptr,
+                                               jax.core.Tracer):
+        d_mat = float(MatrixStats.of(obj).d_mat)
     return n, nnz, d_mat, sig
 
 
@@ -321,17 +293,13 @@ def _width_of(obj: Any) -> int:
 
 
 def _slab_bound_for(obj: Any, g: TileGeometry) -> Optional[int]:
-    """Exact slab coverage bound for a CSR/CCS/BCSR candidate, computable
-    only with the concrete index structure in hand (for CCS the pointer is
-    the column pointer — same arithmetic)."""
-    ip = getattr(obj, "indptr", None)
-    if ip is None or isinstance(ip, jax.core.Tracer):
+    """Exact slab coverage bound for a CSR candidate at the launch the
+    kernel wrapper will actually make, computable only with the concrete
+    index structure in hand."""
+    if isinstance(getattr(obj, "indptr", None), jax.core.Tracer):
         return None
-    from repro.kernels.csr_spmv import slabs_needed
-    segmented = isinstance(obj, (CSR, CCS))
-    br = g.block_rows or (256 if segmented else 32)
-    bn = g.block_nnz or (2048 if segmented else 512)
-    return slabs_needed(np.asarray(ip), br, bn)
+    from repro.kernels.ops import exact_slab_bound
+    return exact_slab_bound(obj, g)
 
 
 # ---------------------------------------------------------------------------
@@ -461,17 +429,9 @@ class KernelTuner:
                                        key, force)
 
         cands: List[Optional[TileGeometry]] = [None]
-        if fmt == "ccs":
-            # the segmented axis is the *column* axis
-            grid_rows = int(getattr(obj, "n_cols", 0) or 0)
-        else:
-            # BCSR row tiles count *block* rows; everything else scalar rows
-            grid_rows = int(getattr(obj, "n_block_rows", profile[0]) or 0)
         grid = candidate_geometries(
-            fmt, op, n_rows=grid_rows, width=_width_of(obj),
-            nnz_pad=int(getattr(obj, "nnz_pad",
-                                getattr(obj, "nblocks_pad", 0)) or 0),
-            batch=batch)
+            fmt, op, n_rows=profile[0], width=_width_of(obj),
+            nnz_pad=int(getattr(obj, "nnz_pad", 0) or 0), batch=batch)
         if self.max_candidates is not None:
             grid = grid[: self.max_candidates]
         cands.extend(grid)
@@ -481,7 +441,7 @@ class KernelTuner:
             times: List[Tuple[float, Optional[TileGeometry]]] = []
             for g in cands:
                 gg = g
-                if g is not None and fmt in ("csr", "ccs", "bcsr"):
+                if g is not None and fmt == "csr":
                     spb = _slab_bound_for(obj, g)
                     if spb is not None:
                         gg = replace(g, slabs_per_block=spb)

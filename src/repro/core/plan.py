@@ -70,7 +70,7 @@ DEFAULT_RECIPE_PARAMS: Dict[str, Dict[str, Any]] = {
 
 #: formats whose kernels carry a data-dependent slab-coverage bound that
 #: must be (re)derived per concrete matrix
-_SLAB_FORMATS = ("csr", "ccs", "bcsr")
+_SLAB_FORMATS = ("csr",)
 
 
 def _finite_or_none(v: float) -> Optional[float]:

@@ -2,8 +2,5 @@
 jit wrappers (ops) and pure-jnp oracles (ref)."""
 from . import ops, ref
 from .ell_spmv import ell_spmv, ell_spmm
-from .coo_spmv import coo_spmv
-from .csr_spmv import csr_spmv, csr_spmm
-from .ccs_spmv import ccs_spmv, ccs_spmm
-from .bcsr_spmv import bcsr_spmv, bcsr_spmm
+from .csr_spmv import csr_spmm_t
 from .decode_attention import decode_attention_int8

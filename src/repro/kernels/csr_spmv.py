@@ -1,34 +1,35 @@
-"""Native row-segmented CSR SpMV / SpMM Pallas TPU kernels.
+"""Native row-segmented CSR SpMV / SpMM Pallas TPU kernel.
 
-Until this module existed the kernel tier served CSR by expanding IRP to
-IROW at call time and running the COO kernel — one sequential grid with the
-whole y vector resident in VMEM.  This kernel keeps CSR native and restores
-row parallelism:
-
-  * the grid is ``(row_blocks, slabs_per_block)`` (SpMM adds a parallel k
-    axis): each row block owns a private ``(block_rows,)`` output tile, so
-    row blocks are *parallel* — there is no whole-matrix y in VMEM and no
+  * the grid is ``(row_blocks, k_blocks, slabs_per_block)``: each row
+    block owns a private ``(block_k, block_rows)`` output tile, so row
+    blocks are *parallel* — there is no whole-matrix y in VMEM and no
     global sequential walk;
   * a row block's nonzeros are contiguous in CSR order
     (``IRP[i*br] : IRP[(i+1)*br]``), so its slabs are located by *scalar
     prefetch*: ``slab_start[i] = IRP[i*br] // block_nnz`` feeds the
-    BlockSpec index map and the VAL/ICOL slabs stream straight out of the
-    row block's own span — the TPU form of the paper's per-thread
-    contiguous CRS walk (§3.1's outer parallelization);
-  * within a slab, each entry's local row is recovered from the row block's
-    IRP window by a compare-count (a vectorized ``searchsorted``), then a
-    short local scatter-add accumulates into the (VMEM-resident) row tile.
+    BlockSpec index map and the VAL slabs stream straight out of the row
+    block's own span — the TPU form of the paper's per-thread contiguous
+    CRS walk (§3.1's outer parallelization);
+  * within a slab, rows are recovered with a one-hot compare: entry ``k``
+    belongs to local row ``r`` iff ``IRP[r] <= k < IRP[r+1]``.  The
+    ``(block_nnz, block_rows)`` 0/1 matrix is contracted against the
+    slab's contributions on the MXU (at full f32 precision), which takes
+    the place of a scatter-add (Mosaic has none).  Entries outside the
+    row block — a neighbour's entries in a shared slab, or tail padding —
+    match no row and contribute nothing.
+
+The x gather happens in XLA before the kernel: ``X[ICOL]`` streams in as
+a lane-dense ``(k, nnz)`` panel beside VAL, so the kernel needs no gather
+and x is never pinned in VMEM.  SpMV is the ``k = 1`` case of the same
+kernel body.
 
 ``slabs_per_block`` must statically bound ``ceil(span / block_nnz) + 1``
-over all row blocks.  It is data-dependent, which is exactly why the launch
+over all row blocks.  It is data-dependent, which is why the launch
 geometry auto-tuner (``core/kernel_tune.py``) exists: tuning happens with
 the concrete matrix in hand, and the winning :class:`TileGeometry` carries
 the exact bound into traced hot paths.  Callers without a bound pass
-``slab_starts=None`` and the kernel degrades to a full sequential sweep per
-row block (always correct, never fast) — see ``slabs_needed``.
-
-Padding conventions match the rest of the repo: pad entries are
-(val=0, col=0) and fall outside every row block's IRP window.
+``slabs_per_block=0`` and every row block sweeps every slab (always
+correct, never fast) — see ``slabs_needed``.
 """
 from __future__ import annotations
 
@@ -58,32 +59,13 @@ def slabs_needed(indptr, block_rows: int, block_nnz: int) -> int:
     return max(int(needed.max()), 1)
 
 
-def _row_windows(indptr: jax.Array, n_rows: int, block_rows: int) -> jax.Array:
-    """(R, block_rows + 1) IRP windows, one per row block; rows past the end
-    get the final pointer (empty rows).  One clipped gather — windows
-    overlap by one entry, so a reshape can't produce them."""
-    r = -(-n_rows // block_rows)
-    ip = jnp.asarray(indptr)
-    if r == 1 and block_rows == n_rows:
-        return ip[None, :]
-    idx = (jnp.arange(r, dtype=jnp.int32)[:, None] * block_rows +
-           jnp.arange(block_rows + 1, dtype=jnp.int32)[None, :])
-    return ip[jnp.minimum(idx, n_rows)]
-
-
-def _pad_slabs(a: jax.Array, n_slabs: int, block_nnz: int) -> jax.Array:
-    target = n_slabs * block_nnz
-    if a.shape[0] < target:
-        a = jnp.pad(a, (0, target - a.shape[0]))
-    return a
-
-
 def _slab_schedule(indptr, r: int, block_rows: int, block_nnz: int,
                    total: int, slabs_per_block: int):
-    """(spb, slab_start) for the (row_blocks, spb) grid.  Tight slab starts
-    are clamped to ``total - spb`` so the furthest reachable slab is always
-    the last real one — a clamped window still covers its block's span
-    (the span's last slab is < total), and no extra padding slabs exist."""
+    """(spb, slab_start) for the (row_blocks, ..., spb) grid.  Tight slab
+    starts are clamped to ``total - spb`` so the furthest reachable slab is
+    always the last real one — a clamped window still covers its block's
+    span (the span's last slab is < total), and no extra padding slabs
+    exist."""
     if slabs_per_block:
         spb = min(slabs_per_block, total)
         start = jnp.asarray(indptr)[::block_rows][:r] // block_nnz
@@ -91,110 +73,18 @@ def _slab_schedule(indptr, r: int, block_rows: int, block_nnz: int,
     return total, jnp.zeros((r,), jnp.int32)
 
 
-def _local_rows(ip_window: jax.Array, k0, bn: int, ip_dtype,
-                interpret: bool = True, masked: bool = True):
-    """Local row id of each global nnz index in ``[k0, k0 + bn)`` within
-    one row block's IRP window, plus the in-window validity mask —
-    semantically ``searchsorted(window, k, 'right') - 1``.
-
-    The slab's indices are a *contiguous* range, so the search inverts into
-    an O(br + bn) scatter + prefix sum over the row *boundaries* (each
-    window pointer marks where the local row increments) — strictly less
-    work than any per-entry search, and the concrete edge this kernel holds
-    over the CSR-via-COO detour, whose IROW expansion must binary-search
-    every nonzero on every call.  The compiled path keeps the VPU-lowerable
-    O(bn x br) compare-count form (Mosaic has no 1D scatter).
-
-    ``masked=False`` skips the validity mask (returns ``valid=None``): with
-    a single row block every stored entry belongs to it and the tail pads
-    carry val=0, contributing nothing wherever they scatter."""
-    br = ip_window.shape[0] - 1
-    k0 = jnp.asarray(k0, ip_dtype)
-    if interpret:
-        marks = jnp.zeros((bn + 1,), jnp.int32).at[
-            jnp.clip(ip_window - k0, 0, bn)].add(1)
-        lrow = jnp.cumsum(marks[:bn]) - 1
-    else:
-        k = k0 + jax.lax.broadcasted_iota(ip_dtype, (bn,), 0)
-        lrow = jnp.sum(ip_window[None, :] <= k[:, None], axis=1) - 1
-    valid = None
-    if masked:
-        k = k0 + jax.lax.broadcasted_iota(ip_dtype, (bn,), 0)
-        valid = (k >= ip_window[0]) & (k < ip_window[br])
-    return jnp.clip(lrow, 0, br - 1), valid
-
-
-def _csr_spmv_kernel(interpret, masked, slab_ref, data_ref, cols_ref,
-                     win_ref, x_ref, y_ref):
-    i, j = pl.program_id(0), pl.program_id(1)
-    bn = data_ref.shape[0]
-    lrow, valid = _local_rows(win_ref[0, :], (slab_ref[i] + j) * bn, bn,
-                              jnp.int32, interpret, masked)
-    contrib = (data_ref[...].astype(jnp.float32) *
-               x_ref[...].astype(jnp.float32)[cols_ref[...]])
-    if valid is not None:
-        contrib = jnp.where(valid, contrib, 0.0)
-    partial = jnp.zeros_like(y_ref).at[lrow].add(contrib)
-
-    @pl.when(j == 0)
-    def _init():
-        y_ref[...] = partial
-
-    @pl.when(j != 0)
-    def _acc():
-        y_ref[...] = y_ref[...] + partial
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "block_nnz",
-                                             "slabs_per_block", "interpret"))
-def csr_spmv(data: jax.Array, cols: jax.Array, indptr: jax.Array,
-             x: jax.Array, *, block_rows: int = 256, block_nnz: int = 2048,
-             slabs_per_block: int = 0, interpret: bool = True) -> jax.Array:
-    """y = A @ x, A in CSR (VAL/ICOL padded with zeros past IRP[-1]).
-
-    ``slabs_per_block``: static bound from :func:`slabs_needed` (scalar-
-    prefetched tight slab starts); 0 selects the always-correct full sweep
-    (every row block scans every slab).  Returns (n_rows,) float32; callers
-    cast (the ops wrapper keeps the repo's f32-accumulate convention)."""
-    n_rows = indptr.shape[0] - 1
-    r = -(-n_rows // block_rows)
-    total = -(-data.shape[0] // block_nnz)
-    spb, slab_start = _slab_schedule(indptr, r, block_rows, block_nnz,
-                                     total, slabs_per_block)
-    win = _row_windows(indptr, n_rows, block_rows)
-    data = _pad_slabs(data, total, block_nnz)
-    cols = _pad_slabs(cols, total, block_nnz)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r, spb),
-        in_specs=[
-            pl.BlockSpec((block_nnz,), lambda i, j, s: (s[i] + j,)),
-            pl.BlockSpec((block_nnz,), lambda i, j, s: (s[i] + j,)),
-            pl.BlockSpec((1, block_rows + 1), lambda i, j, s: (i, 0)),
-            pl.BlockSpec(x.shape, lambda i, j, s: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_rows,), lambda i, j, s: (i,)),
-    )
-    y = pl.pallas_call(
-        functools.partial(_csr_spmv_kernel, interpret, r > 1),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r * block_rows,), jnp.float32),
-        interpret=interpret,
-    )(slab_start.astype(jnp.int32), data, cols, win, x)
-    return y[:n_rows]
-
-
-def _csr_spmm_kernel(interpret, masked, slab_ref, data_ref, cols_ref,
-                     win_ref, x_ref, y_ref):
+def _csr_kernel(slab_ref, start_ref, end_ref, data_ref, xg_ref, y_ref):
     i, j = pl.program_id(0), pl.program_id(2)
-    bn = data_ref.shape[0]
-    lrow, valid = _local_rows(win_ref[0, :], (slab_ref[i] + j) * bn, bn,
-                              jnp.int32, interpret, masked)
-    gathered = x_ref[...].astype(jnp.float32)[cols_ref[...], :]
-    contrib = data_ref[...].astype(jnp.float32)[:, None] * gathered
-    if valid is not None:
-        contrib = jnp.where(valid[:, None], contrib, 0.0)
-    partial = jnp.zeros_like(y_ref).at[lrow, :].add(contrib)
+    bn = data_ref.shape[1]
+    k = ((slab_ref[i] + j) * bn +
+         jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0))
+    onehot = ((k >= start_ref[...]) & (k < end_ref[...])
+              ).astype(jnp.float32)                       # (bn, br)
+    contrib = (data_ref[...].astype(jnp.float32) *
+               xg_ref[...].astype(jnp.float32))           # (bk, bn)
+    partial = jnp.dot(contrib, onehot,
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(j == 0)
     def _init():
@@ -208,40 +98,44 @@ def _csr_spmm_kernel(interpret, masked, slab_ref, data_ref, cols_ref,
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_nnz",
                                              "block_k", "slabs_per_block",
                                              "interpret"))
-def csr_spmm(data: jax.Array, cols: jax.Array, indptr: jax.Array,
-             x: jax.Array, *, block_rows: int = 256, block_nnz: int = 2048,
-             block_k: int = 128, slabs_per_block: int = 0,
-             interpret: bool = True) -> jax.Array:
-    """Y = A @ X, A in CSR, X (n_cols, k) -> Y (n_rows, k) float32.
-
-    Grid = (row_blocks, k_blocks, slabs); slabs are the innermost
-    (sequential accumulation) axis, rows and k parallel."""
+def csr_spmm_t(data: jax.Array, xg_t: jax.Array, indptr: jax.Array, *,
+               block_rows: int = 256, block_nnz: int = 2048,
+               block_k: int = 1, slabs_per_block: int = 0,
+               interpret: bool = False) -> jax.Array:
+    """Y^T = (A @ X)^T with A in CSR: ``data`` is VAL ``(nnz_pad,)`` (zeros
+    past ``IRP[-1]``) and ``xg_t`` is the gathered ``X[ICOL]^T``,
+    ``(k, nnz_pad)``.  ``nnz_pad`` must be a multiple of ``block_nnz``
+    and ``k`` of ``block_k``.  Returns ``(k, row_blocks * block_rows)``
+    float32; rows past ``n_rows`` are zero."""
     n_rows = indptr.shape[0] - 1
-    n_cols, kk = x.shape
-    assert kk % block_k == 0, (kk, block_k)
+    kk, nnz_pad = xg_t.shape
+    assert nnz_pad % block_nnz == 0 and kk % block_k == 0, (
+        xg_t.shape, block_nnz, block_k)
     r = -(-n_rows // block_rows)
-    total = -(-data.shape[0] // block_nnz)
+    total = nnz_pad // block_nnz
     spb, slab_start = _slab_schedule(indptr, r, block_rows, block_nnz,
                                      total, slabs_per_block)
-    win = _row_windows(indptr, n_rows, block_rows)
-    data = _pad_slabs(data, total, block_nnz)
-    cols = _pad_slabs(cols, total, block_nnz)
+    ip = jnp.asarray(indptr, jnp.int32)
+    pad = r * block_rows - n_rows
+    starts = jnp.pad(ip[:-1], (0, pad), constant_values=ip[-1])[None, :]
+    ends = jnp.pad(ip[1:], (0, pad), constant_values=ip[-1])[None, :]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(r, kk // block_k, spb),
         in_specs=[
-            pl.BlockSpec((block_nnz,), lambda i, c, j, s: (s[i] + j,)),
-            pl.BlockSpec((block_nnz,), lambda i, c, j, s: (s[i] + j,)),
-            pl.BlockSpec((1, block_rows + 1), lambda i, c, j, s: (i, 0)),
-            pl.BlockSpec((n_cols, block_k), lambda i, c, j, s: (0, c)),
+            pl.BlockSpec((1, block_rows), lambda i, c, j, s: (0, i)),
+            pl.BlockSpec((1, block_rows), lambda i, c, j, s: (0, i)),
+            pl.BlockSpec((1, block_nnz), lambda i, c, j, s: (0, s[i] + j)),
+            pl.BlockSpec((block_k, block_nnz),
+                         lambda i, c, j, s: (c, s[i] + j)),
         ],
-        out_specs=pl.BlockSpec((block_rows, block_k),
-                               lambda i, c, j, s: (i, c)),
+        out_specs=pl.BlockSpec((block_k, block_rows),
+                               lambda i, c, j, s: (c, i)),
     )
-    y = pl.pallas_call(
-        functools.partial(_csr_spmm_kernel, interpret, r > 1),
+    return pl.pallas_call(
+        _csr_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r * block_rows, kk), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((kk, r * block_rows), jnp.float32),
         interpret=interpret,
-    )(slab_start.astype(jnp.int32), data, cols, win, x)
-    return y[:n_rows]
+        name="csr_spmm",
+    )(slab_start.astype(jnp.int32), starts, ends, data[None, :], xg_t)

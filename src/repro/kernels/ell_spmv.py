@@ -1,24 +1,27 @@
-"""ELL SpMV / SpMM Pallas TPU kernel — the paper's hero format.
+"""ELL SpMV / SpMM Pallas TPU kernels — the paper's hero format.
 
 TPU mapping of the paper's §3.3/§3.4 parallelizations:
   * the N-loop (rows) becomes the *parallel* grid axis, tiled in
-    ``block_rows`` chunks (paper's "outer" parallelization);
+    ``block_rows`` chunks laid along the 128 vector lanes (the paper's
+    "outer" parallelization);
   * the NE-loop (band) becomes the sequential accumulation axis, tiled in
-    ``block_w`` lanes (paper's "inner" parallelization) — both schedules
-    coexist in one kernel because the mesh/grid split covers both.
+    ``block_w`` sublane rows (the paper's "inner" parallelization).
 
-VMEM strategy: the dense x vector is pinned whole in VMEM (n_cols * 4 B;
-up to ~1M columns fits the ~16 MB of a v5e core alongside the tiles), while
-the (rows, width) VAL/ICOL panels stream through in
-(block_rows, block_w) blocks.  The inner product is a VPU gather
-(x[ICOL-block]) followed by a dense multiply-reduce over the minor
-(lane-aligned) axis — full lane utilization, unlike CSR's short
-row-segmented reductions.  This is exactly why the paper's ES2 vector
-pipes love ELL; the TPU inherits the preference.
+Layout: the kernels take the band *transposed*, ``(width, n_rows)`` — the
+paper's ELL-Col storage — so one band slot of ``block_rows`` consecutive
+rows is one lane-dense vector row, and the reduction over the band is a
+sublane reduction.  The output is a lane-dense ``(1, n_rows)`` row
+(SpMM: ``(k, n_rows)``), never a 1-D or lane-width-1 block.
 
-Block alignment: block_rows % 8 == 0 (sublane), block_w % 128 == 0 (lane).
-The ops.py wrapper pads inputs to these multiples (pad entries: val=0,
-col=0 — contributing zero, the paper's own padding convention).
+The x gather happens in XLA before the kernel: ``x[ICOL]`` is streamed in
+as a dense panel of the same shape as VAL, so the kernel body is a dense
+multiply-reduce (Mosaic has no general 1-D gather).  x is therefore never
+pinned in VMEM, and its size is bounded by HBM only.
+
+Block alignment: ``block_w % 8 == 0`` (sublanes); ``block_rows`` is a
+multiple of 128 (lanes) or covers every row.  The ops.py wrapper pads
+inputs to these multiples (pad entries: val=0, col=0 — contributing zero,
+the paper's own padding convention).
 """
 from __future__ import annotations
 
@@ -29,14 +32,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _ell_spmv_kernel(data_ref, cols_ref, x_ref, y_ref):
-    """Grid = (row_blocks, w_blocks); w is the sequential accumulation axis.
-    Accumulation is always f32 (standard MXU/VPU practice for bf16 inputs)."""
+def _ell_spmv_kernel(data_ref, xg_ref, y_ref):
+    """Grid = (row_blocks, w_blocks); w is the sequential accumulation
+    axis.  Accumulation is always f32."""
     j = pl.program_id(1)
-    x = x_ref[...]
-    gathered = x[cols_ref[...]]                 # (block_rows, block_w) gather
     partial = jnp.sum(data_ref[...].astype(jnp.float32) *
-                      gathered.astype(jnp.float32), axis=1)
+                      xg_ref[...].astype(jnp.float32), axis=0,
+                      keepdims=True)                      # (1, block_rows)
 
     @pl.when(j == 0)
     def _init():
@@ -49,43 +51,42 @@ def _ell_spmv_kernel(data_ref, cols_ref, x_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_w",
                                              "interpret"))
-def ell_spmv(data: jax.Array, cols: jax.Array, x: jax.Array, *,
-             block_rows: int = 256, block_w: int = 128,
-             interpret: bool = True) -> jax.Array:
-    """y = A @ x, A in ELL-Row: data/cols (n_rows, width), x (n_cols,).
+def ell_spmv(data_t: jax.Array, xg_t: jax.Array, *, block_rows: int = 256,
+             block_w: int = 128, interpret: bool = False) -> jax.Array:
+    """y = A @ x with A's band transposed: ``data_t``/``xg_t`` are
+    ``(width, n_rows)`` — VAL and the gathered ``x[ICOL]``.
 
-    Shapes must already be block-aligned (see ops.ell_spmv for the padding
-    wrapper).  Returns (n_rows,) in x.dtype's result type."""
-    n_rows, width = data.shape
+    Shapes must already be block-aligned (see ops.ell_spmv_raw for the
+    padding wrapper).  Returns ``(n_rows,)`` float32."""
+    width, n_rows = data_t.shape
     assert n_rows % block_rows == 0 and width % block_w == 0, (
-        f"unaligned ELL shapes {data.shape} for blocks "
+        f"unaligned ELL shapes {data_t.shape} for blocks "
         f"({block_rows},{block_w})")
-    grid = (n_rows // block_rows, width // block_w)
-    out_dtype = jnp.result_type(data.dtype, x.dtype)
-    y32 = pl.pallas_call(
+    y = pl.pallas_call(
         _ell_spmv_kernel,
-        grid=grid,
+        grid=(n_rows // block_rows, width // block_w),
         in_specs=[
-            pl.BlockSpec((block_rows, block_w), lambda i, j: (i, j)),
-            pl.BlockSpec((block_rows, block_w), lambda i, j: (i, j)),
-            pl.BlockSpec(x.shape, lambda i, j: (0,)),     # x whole in VMEM
+            pl.BlockSpec((block_w, block_rows), lambda i, j: (j, i)),
+            pl.BlockSpec((block_w, block_rows), lambda i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((block_rows,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_rows,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_rows), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n_rows), jnp.float32),
         interpret=interpret,
-    )(data, cols, x)
-    return y32.astype(out_dtype)
+        name="ell_spmv",
+    )(data_t, xg_t)
+    return y[0]
 
 
-def _ell_spmm_kernel(data_ref, cols_ref, x_ref, y_ref):
-    """SpMM: multi-vector RHS x (n_cols, k).  Grid = (row_blocks, k_blocks,
-    w_blocks); w is innermost (sequential accumulation — consecutive visits
-    to each output block, as TPU Pallas requires), rows/k parallel."""
+def _ell_spmm_kernel(data_ref, xg_ref, y_ref):
+    """Multi-RHS: ``xg_ref`` is ``(block_w, block_k, block_rows)`` — the
+    gathered x rows of each band slot, right-hand sides on sublanes.
+    Grid = (row_blocks, k_blocks, w_blocks); w innermost (sequential)."""
     j = pl.program_id(2)
-    x = x_ref[...]                               # (n_cols, block_k)
-    gathered = x[cols_ref[...], :]               # (br, bw, block_k)
-    partial = jnp.einsum("rw,rwk->rk", data_ref[...], gathered,
-                         preferred_element_type=jnp.float32)
+    data = data_ref[...].astype(jnp.float32)              # (bw, br)
+    partial = jnp.zeros(y_ref.shape, jnp.float32)         # (bk, br)
+    for w in range(data.shape[0]):
+        partial = partial + data[w:w + 1, :] * \
+            xg_ref[w].astype(jnp.float32)
 
     @pl.when(j == 0)
     def _init():
@@ -98,27 +99,27 @@ def _ell_spmm_kernel(data_ref, cols_ref, x_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_w",
                                              "block_k", "interpret"))
-def ell_spmm(data: jax.Array, cols: jax.Array, x: jax.Array, *,
-             block_rows: int = 128, block_w: int = 128, block_k: int = 128,
-             interpret: bool = True) -> jax.Array:
-    """Y = A @ X, A in ELL-Row, X (n_cols, k) -> Y (n_rows, k)."""
-    n_rows, width = data.shape
-    n_cols, k = x.shape
+def ell_spmm(data_t: jax.Array, xg_t: jax.Array, *, block_rows: int = 128,
+             block_w: int = 8, block_k: int = 128,
+             interpret: bool = False) -> jax.Array:
+    """Y = A @ X with ``data_t`` ``(width, n_rows)`` and ``xg_t``
+    ``(width, k, n_rows)`` (``X[ICOL]`` with the RHS axis in the middle).
+    Returns Y transposed, ``(k, n_rows)`` float32."""
+    width, n_rows = data_t.shape
+    k = xg_t.shape[1]
     assert n_rows % block_rows == 0 and width % block_w == 0 \
-        and k % block_k == 0, (data.shape, x.shape)
-    grid = (n_rows // block_rows, k // block_k, width // block_w)
-    out_dtype = jnp.result_type(data.dtype, x.dtype)
-    y32 = pl.pallas_call(
+        and k % block_k == 0, (data_t.shape, xg_t.shape)
+    return pl.pallas_call(
         _ell_spmm_kernel,
-        grid=grid,
+        grid=(n_rows // block_rows, k // block_k, width // block_w),
         in_specs=[
-            pl.BlockSpec((block_rows, block_w), lambda i, kk, j: (i, j)),
-            pl.BlockSpec((block_rows, block_w), lambda i, kk, j: (i, j)),
-            pl.BlockSpec((n_cols, block_k), lambda i, kk, j: (0, kk)),
+            pl.BlockSpec((block_w, block_rows), lambda i, kk, j: (j, i)),
+            pl.BlockSpec((block_w, block_k, block_rows),
+                         lambda i, kk, j: (j, kk, i)),
         ],
-        out_specs=pl.BlockSpec((block_rows, block_k),
-                               lambda i, kk, j: (i, kk)),
-        out_shape=jax.ShapeDtypeStruct((n_rows, k), jnp.float32),
+        out_specs=pl.BlockSpec((block_k, block_rows),
+                               lambda i, kk, j: (kk, i)),
+        out_shape=jax.ShapeDtypeStruct((k, n_rows), jnp.float32),
         interpret=interpret,
-    )(data, cols, x)
-    return y32.astype(out_dtype)
+        name="ell_spmm",
+    )(data_t, xg_t)

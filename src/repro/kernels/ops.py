@@ -1,29 +1,28 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Responsibilities:
-  * pad raw arrays to TPU block alignment (val=0/col=0 padding — the
-    paper's own ELL zero-fill convention, so padding never changes results);
+  * gather ``x[ICOL]`` in XLA and lay VAL and the gathered panel out the
+    way the kernels stream them (band-major for ELL, lane-dense rows for
+    CSR), padded to legal TPU blocks (val=0/col=0 padding — the paper's
+    own ELL zero-fill convention, so padding never changes results);
   * accept the ``repro.core.formats`` pytree classes;
-  * provide custom VJPs so the kernels are trainable (y = A@x  =>
-    dx = A^T dy via a COO scatter; dA = dy_r * x_c at the stored positions);
-  * auto-select interpret mode off-TPU;
+  * provide a custom VJP so the ELL kernel is trainable (y = A@x  =>
+    dx = A^T dy via a scatter; dA = dy_r * x_c at the stored positions);
+  * choose compiled mode on a TPU and interpret mode elsewhere — one
+    kernel body for both, so the CPU tests check the code the chip runs;
   * accept a per-call launch geometry (``tuning=`` — a
     ``core.kernel_tune.TileGeometry``); ``None`` fields fall back to the
-    built-in defaults below, so the kernel launch-geometry auto-tuner can
-    override exactly the knobs it searched;
+    built-in defaults below, and every tile is rounded to a shape the TPU
+    compiler accepts (lane tiles: a multiple of 128 or the whole axis);
   * register every format-level wrapper in the ``repro.core.dispatch``
     registry under the ``"kernel"`` tier — ``KERNEL_SPMV_IMPLS`` /
     ``KERNEL_SPMM_IMPLS`` below are views of that registry, kept for
     callers that want a plain dict.
 
-CSR is served by the native row-segmented kernel (``kernels/csr_spmv.py``);
-the old CSR-via-COO detour survives only as ``spmv_csr_via_coo`` /
-``spmm_csr_via_coo`` so benchmarks can measure what replacing it bought.
-CCS is served by the column-segmented mirror (``kernels/ccs_spmv.py``) —
-every registered base format now has a native kernel.  SELL accepts a
-*per-bucket* launch geometry (a ``TileGeometry`` carrying a
-``buckets`` table, a ``{width: TileGeometry}`` mapping, or a positional
-sequence) so each bucket launches with its own tile shape.
+The kernel tier covers CSR (``kernels/csr_spmv.py``), ELL in both storage
+orders and SELL (``kernels/ell_spmv.py``, one launch per bucket), and the
+hybrid container (each block through its format's kernel).  COO, CCS and
+BCSR are served by the reference tier.
 """
 from __future__ import annotations
 
@@ -35,11 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import dispatch as _dispatch
-from repro.core.formats import BCSR, CCS, COO, CSR, ELL, BucketedELL
-from repro.core.kernel_tune import TileGeometry
-from . import bcsr_spmv as _bcsr
-from . import ccs_spmv as _ccsk
-from . import coo_spmv as _coo
+from repro.core.formats import CSR, ELL, BucketedELL
+from repro.core.kernel_tune import MAX_TILE, TileGeometry
 from . import csr_spmv as _csr
 from . import ell_spmv as _ell
 
@@ -49,7 +45,13 @@ def _on_tpu() -> bool:
 
 
 def _interpret(flag: Optional[bool]) -> bool:
-    return (not _on_tpu()) if flag is None else flag
+    """Compiled on a TPU, interpreted elsewhere.  Asking for the
+    interpreter on a TPU is an error: it would serve results at a small
+    fraction of the chip's speed without saying so."""
+    on_tpu = _on_tpu()
+    if flag and on_tpu:
+        raise ValueError("Pallas interpret mode requested on a TPU backend")
+    return (not on_tpu) if flag is None else flag
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
@@ -66,18 +68,12 @@ def _align8(n: int) -> int:
     return max(8, 8 * ((int(n) + 7) // 8))
 
 
-def _block_sizes(n_rows: int, width: int) -> Tuple[int, int]:
-    """Default tile shape: rows capped at 256 sublanes; the band tile is the
-    smallest 8-aligned width covering the band, capped at 128 lanes — a
-    40-wide band used to be padded to 128 lanes (up to 16x wasted work per
-    tile), now it gets a 40-lane tile."""
-    br = min(256, _align8(n_rows))
-    bw = min(128, _align8(width))
-    return br, bw
-
-
-def _block_k(k: int) -> int:
-    return min(128, _align8(k))
+def _lane_tile(v: int, n: int) -> int:
+    """A tile along a lane (minor) axis of extent ``n`` that the TPU
+    compiler accepts: a multiple of 128, or the whole 8-aligned axis."""
+    full = _align8(n)
+    t = 128 * -(-int(v) // 128)
+    return full if t >= full else t
 
 
 def _geom(tuning: Optional[TileGeometry], name: str, default: int,
@@ -87,69 +83,75 @@ def _geom(tuning: Optional[TileGeometry], name: str, default: int,
     return min(v, cap) if cap is not None else v
 
 
+def _block_sizes(n_rows: int, width: int) -> Tuple[int, int]:
+    """Default ELL tile: 256 rows along the lanes (or every row of a
+    smaller matrix); the band tile is the smallest 8-aligned cover of the
+    band, capped at 128 — a 40-slot band gets a 40-slot tile, never a
+    128-slot one."""
+    return _lane_tile(256, n_rows), min(128, _align8(width))
+
+
+def _block_k(k: int) -> int:
+    return min(128, _align8(k))
+
+
+def _gather_rhs(x: jax.Array, idx: jax.Array, axis: int) -> jax.Array:
+    """``x[idx]`` for every right-hand side (column of ``x``), stacked on
+    ``axis``.  One 1-D gather per column keeps each gathered panel
+    lane-dense; a single 2-D gather would materialize an intermediate
+    with the right-hand sides minor, padded to 128 lanes."""
+    return jnp.stack([x[:, i][idx] for i in range(x.shape[1])], axis=axis)
+
+
 # ---------------------------------------------------------------------------
-# raw-array entry points (padding + alignment)
+# ELL: band-major (width, n_rows) arrays
 # ---------------------------------------------------------------------------
+def _ell_geometry(n_rows: int, width: int,
+                  tuning: Optional[TileGeometry]) -> Tuple[int, int]:
+    br0, bw0 = _block_sizes(n_rows, width)
+    br = _lane_tile(_geom(tuning, "block_rows", br0), n_rows)
+    bw = _geom(tuning, "block_w", bw0, cap=_align8(width))
+    return br, bw
+
+
+def _ell_t_spmv(data_t: jax.Array, cols_t: jax.Array, x: jax.Array,
+                interpret: Optional[bool],
+                tuning: Optional[TileGeometry]) -> jax.Array:
+    width, n_rows = data_t.shape
+    br, bw = _ell_geometry(n_rows, width, tuning)
+    data_t = _pad_to(_pad_to(data_t, 1, br), 0, bw)
+    cols_t = _pad_to(_pad_to(cols_t, 1, br), 0, bw)
+    y = _ell.ell_spmv(data_t, x[cols_t], block_rows=br, block_w=bw,
+                      interpret=_interpret(interpret))
+    return y[:n_rows].astype(jnp.result_type(data_t.dtype, x.dtype))
+
+
+def _ell_t_spmm(data_t: jax.Array, cols_t: jax.Array, x: jax.Array,
+                interpret: Optional[bool],
+                tuning: Optional[TileGeometry]) -> jax.Array:
+    width, n_rows = data_t.shape
+    k = x.shape[1]
+    br, bw = _ell_geometry(n_rows, width, tuning)
+    bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
+    data_t = _pad_to(_pad_to(data_t, 1, br), 0, bw)
+    cols_t = _pad_to(_pad_to(cols_t, 1, br), 0, bw)
+    xg_t = _gather_rhs(_pad_to(x, 1, bk), cols_t, axis=1)   # (W, k, n)
+    y_t = _ell.ell_spmm(data_t, xg_t, block_rows=br, block_w=bw,
+                        block_k=bk, interpret=_interpret(interpret))
+    return y_t[:k, :n_rows].T.astype(jnp.result_type(data_t.dtype, x.dtype))
+
+
 def ell_spmv_raw(data: jax.Array, cols: jax.Array, x: jax.Array,
                  interpret: Optional[bool] = None,
                  tuning: Optional[TileGeometry] = None) -> jax.Array:
-    n_rows, width = data.shape
-    br0, bw0 = _block_sizes(n_rows, width)
-    br = _geom(tuning, "block_rows", br0, cap=_align8(n_rows))
-    bw = _geom(tuning, "block_w", bw0, cap=_align8(width))
-    data = _pad_to(_pad_to(data, 0, br), 1, bw)
-    cols = _pad_to(_pad_to(cols, 0, br), 1, bw)
-    y = _ell.ell_spmv(data, cols, x, block_rows=br, block_w=bw,
-                      interpret=_interpret(interpret))
-    return y[:n_rows]
+    """ELL-Row arrays ``(n_rows, width)``."""
+    return _ell_t_spmv(data.T, cols.T, x, interpret, tuning)
 
 
 def ell_spmm_raw(data: jax.Array, cols: jax.Array, x: jax.Array,
                  interpret: Optional[bool] = None,
                  tuning: Optional[TileGeometry] = None) -> jax.Array:
-    n_rows, width = data.shape
-    k = x.shape[1]
-    _, bw0 = _block_sizes(n_rows, width)
-    br = _geom(tuning, "block_rows", min(128, _align8(n_rows)),
-               cap=_align8(n_rows))
-    bw = _geom(tuning, "block_w", bw0, cap=_align8(width))
-    bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
-    data = _pad_to(_pad_to(data, 0, br), 1, bw)
-    cols = _pad_to(_pad_to(cols, 0, br), 1, bw)
-    xp = _pad_to(x, 1, bk)
-    y = _ell.ell_spmm(data, cols, xp, block_rows=br, block_w=bw, block_k=bk,
-                      interpret=_interpret(interpret))
-    return y[:n_rows, :k]
-
-
-def coo_spmv_raw(data: jax.Array, rows: jax.Array, cols: jax.Array,
-                 x: jax.Array, n_rows: int,
-                 interpret: Optional[bool] = None,
-                 tuning: Optional[TileGeometry] = None) -> jax.Array:
-    bn = _geom(tuning, "block_nnz", min(4096, _align8(data.shape[0])),
-               cap=_align8(data.shape[0]))
-    data = _pad_to(data, 0, bn)
-    rows = _pad_to(rows, 0, bn)
-    cols = _pad_to(cols, 0, bn)
-    return _coo.coo_spmv(data, rows, cols, x, n_rows=n_rows, block_nnz=bn,
-                         interpret=_interpret(interpret))
-
-
-def coo_spmm_raw(data: jax.Array, rows: jax.Array, cols: jax.Array,
-                 x: jax.Array, n_rows: int,
-                 interpret: Optional[bool] = None,
-                 tuning: Optional[TileGeometry] = None) -> jax.Array:
-    k = x.shape[1]
-    bn = _geom(tuning, "block_nnz", min(4096, _align8(data.shape[0])),
-               cap=_align8(data.shape[0]))
-    bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
-    data = _pad_to(data, 0, bn)
-    rows = _pad_to(rows, 0, bn)
-    cols = _pad_to(cols, 0, bn)
-    xp = _pad_to(x, 1, bk)
-    y = _coo.coo_spmm(data, rows, cols, xp, n_rows=n_rows, block_nnz=bn,
-                      block_k=bk, interpret=_interpret(interpret))
-    return y[:, :k]
+    return _ell_t_spmm(data.T, cols.T, x, interpret, tuning)
 
 
 # ---------------------------------------------------------------------------
@@ -166,7 +168,7 @@ def _ell_fwd(data, cols, x):
 
 def _ell_bwd(res, dy):
     data, cols, x = res
-    # dx[c] = sum_{r,k: cols[r,k]=c} data[r,k] * dy[r]   (A^T dy, COO scatter)
+    # dx[c] = sum_{r,k: cols[r,k]=c} data[r,k] * dy[r]   (A^T dy, scatter)
     dx = jnp.zeros_like(x).at[cols.reshape(-1)].add(
         (data * dy[:, None]).reshape(-1).astype(x.dtype))
     # dA[r,k] = dy[r] * x[cols[r,k]]
@@ -180,40 +182,50 @@ ell_spmv_ad.defvjp(_ell_fwd, _ell_bwd)
 # ---------------------------------------------------------------------------
 # format-level entry points (what the auto-tuner plugs in)
 # ---------------------------------------------------------------------------
-def _ell_arrays(m: ELL):
+def _ell_arrays_t(m: ELL):
+    """VAL/ICOL band-major, ``(width, n_rows)``: ELL-Col as stored,
+    ELL-Row transposed."""
     data, cols = jnp.asarray(m.data), jnp.asarray(m.cols)
-    if m.order == "col":
+    if m.order == "row":
         data, cols = data.T, cols.T
     return data, cols
 
 
 def spmv_ell(m: ELL, x: jax.Array, interpret: Optional[bool] = None,
              tuning: Optional[TileGeometry] = None) -> jax.Array:
-    data, cols = _ell_arrays(m)
-    return ell_spmv_raw(data, cols, x, interpret, tuning)
+    return _ell_t_spmv(*_ell_arrays_t(m), x, interpret, tuning)
 
 
 def spmm_ell(m: ELL, x: jax.Array, interpret: Optional[bool] = None,
              tuning: Optional[TileGeometry] = None) -> jax.Array:
-    data, cols = _ell_arrays(m)
-    return ell_spmm_raw(data, cols, x, interpret, tuning)
-
-
-def spmv_coo(m: COO, x: jax.Array, interpret: Optional[bool] = None,
-             tuning: Optional[TileGeometry] = None) -> jax.Array:
-    return coo_spmv_raw(jnp.asarray(m.data), jnp.asarray(m.rows),
-                        jnp.asarray(m.cols), x, m.n_rows, interpret, tuning)
-
-
-def spmm_coo(m: COO, x: jax.Array, interpret: Optional[bool] = None,
-             tuning: Optional[TileGeometry] = None) -> jax.Array:
-    return coo_spmm_raw(jnp.asarray(m.data), jnp.asarray(m.rows),
-                        jnp.asarray(m.cols), x, m.n_rows, interpret, tuning)
+    return _ell_t_spmm(*_ell_arrays_t(m), x, interpret, tuning)
 
 
 # ---------------------------------------------------------------------------
 # CSR — native row-segmented kernel (kernels/csr_spmv.py)
 # ---------------------------------------------------------------------------
+def _csr_geometry(m: CSR, tuning: Optional[TileGeometry]) -> Tuple[int, int]:
+    """Effective (block_rows, block_nnz): both are lane tiles, and their
+    product (the one-hot row-recovery tile) stays within ``MAX_TILE``."""
+    br = _lane_tile(_geom(tuning, "block_rows", 256), m.n_rows)
+    bn = _lane_tile(_geom(tuning, "block_nnz", 2048), m.nnz_pad)
+    cap = max(128, (MAX_TILE // br) // 128 * 128)
+    return br, (bn if bn <= cap else cap)
+
+
+def exact_slab_bound(m: CSR, tuning: Optional[TileGeometry] = None) -> int:
+    """Concrete slab-coverage bound for a CSR instance at the wrapper's own
+    *effective* launch geometry (tile knobs get clamped to the instance,
+    so the bound must be derived post-clamp).  For baking one bound into a
+    geometry shared by sibling blocks, take the max over the blocks — a
+    larger bound only adds masked slabs, never drops entries."""
+    if not isinstance(m, CSR):
+        raise TypeError(f"no slab-coverage bound for {type(m)}")
+    t = tuning.without_slab_bound() if tuning is not None else None
+    br, bn = _csr_geometry(m, t)
+    return _csr.slabs_needed(np.asarray(m.indptr), br, bn)
+
+
 def _csr_slab_bound(m: CSR, br: int, bn: int,
                     tuning: Optional[TileGeometry]) -> int:
     """Static slab-coverage bound: exact when the index structure is
@@ -227,180 +239,34 @@ def _csr_slab_bound(m: CSR, br: int, bn: int,
     return 0
 
 
+def _csr_launch(m: CSR, xg_t: jax.Array, bk: int,
+                interpret: Optional[bool],
+                tuning: Optional[TileGeometry]) -> jax.Array:
+    br, bn = _csr_geometry(m, tuning)
+    spb = _csr_slab_bound(m, br, bn, tuning)
+    data = _pad_to(jnp.asarray(m.data), 0, bn)
+    return _csr.csr_spmm_t(data, _pad_to(xg_t, 1, bn),
+                           jnp.asarray(m.indptr), block_rows=br,
+                           block_nnz=bn, block_k=bk, slabs_per_block=spb,
+                           interpret=_interpret(interpret))
+
+
 def spmv_csr(m: CSR, x: jax.Array, interpret: Optional[bool] = None,
              tuning: Optional[TileGeometry] = None) -> jax.Array:
-    """CSR through the native row-segmented kernel (no COO detour)."""
-    br = _geom(tuning, "block_rows", min(256, _align8(m.n_rows)),
-               cap=_align8(m.n_rows))
-    bn = _geom(tuning, "block_nnz", min(2048, _align8(m.nnz_pad)),
-               cap=_align8(m.nnz_pad))
-    spb = _csr_slab_bound(m, br, bn, tuning)
-    y = _csr.csr_spmv(jnp.asarray(m.data), jnp.asarray(m.cols),
-                      jnp.asarray(m.indptr), x, block_rows=br, block_nnz=bn,
-                      slabs_per_block=spb, interpret=_interpret(interpret))
-    return y.astype(jnp.result_type(m.data.dtype, x.dtype))
+    """CSR through the native row-segmented kernel (SpMV is its k=1 case)."""
+    xg_t = x[jnp.asarray(m.cols)][None, :]
+    y_t = _csr_launch(m, xg_t, 1, interpret, tuning)
+    return y_t[0, :m.n_rows].astype(jnp.result_type(m.data.dtype, x.dtype))
 
 
 def spmm_csr(m: CSR, x: jax.Array, interpret: Optional[bool] = None,
              tuning: Optional[TileGeometry] = None) -> jax.Array:
     k = x.shape[1]
-    br = _geom(tuning, "block_rows", min(256, _align8(m.n_rows)),
-               cap=_align8(m.n_rows))
-    bn = _geom(tuning, "block_nnz", min(2048, _align8(m.nnz_pad)),
-               cap=_align8(m.nnz_pad))
     bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
-    spb = _csr_slab_bound(m, br, bn, tuning)
-    xp = _pad_to(x, 1, bk)
-    y = _csr.csr_spmm(jnp.asarray(m.data), jnp.asarray(m.cols),
-                      jnp.asarray(m.indptr), xp, block_rows=br, block_nnz=bn,
-                      block_k=bk, slabs_per_block=spb,
-                      interpret=_interpret(interpret))
-    return y[:, :k].astype(jnp.result_type(m.data.dtype, x.dtype))
-
-
-def _csr_as_coo_arrays(m: CSR):
-    """The jit-able IRP->IROW expansion — the pre-native CSR kernel path,
-    kept for the tuned-vs-detour benchmark comparison."""
-    ip = jnp.asarray(m.indptr)
-    k = jnp.arange(m.nnz_pad, dtype=ip.dtype)
-    rows = jnp.clip(jnp.searchsorted(ip, k, side="right") - 1, 0, m.n_rows - 1)
-    rows = jnp.where(k < m.nnz, rows, 0).astype(jnp.int32)
-    data = jnp.where(k < m.nnz, jnp.asarray(m.data), 0)
-    return data, rows, jnp.asarray(m.cols)
-
-
-def spmv_csr_via_coo(m: CSR, x: jax.Array,
-                     interpret: Optional[bool] = None,
-                     tuning: Optional[TileGeometry] = None) -> jax.Array:
-    """Legacy CSR path: IRP->IROW expansion + the COO kernel (benchmark
-    baseline only — the registry serves :func:`spmv_csr`)."""
-    data, rows, cols = _csr_as_coo_arrays(m)
-    return coo_spmv_raw(data, rows, cols, x, m.n_rows, interpret, tuning)
-
-
-def spmm_csr_via_coo(m: CSR, x: jax.Array,
-                     interpret: Optional[bool] = None,
-                     tuning: Optional[TileGeometry] = None) -> jax.Array:
-    data, rows, cols = _csr_as_coo_arrays(m)
-    return coo_spmm_raw(data, rows, cols, x, m.n_rows, interpret, tuning)
-
-
-# ---------------------------------------------------------------------------
-# CCS — native column-segmented kernel (kernels/ccs_spmv.py)
-# ---------------------------------------------------------------------------
-def _ccs_slab_bound(m: CCS, bc: int, bn: int,
-                    tuning: Optional[TileGeometry]) -> int:
-    """Static slab-coverage bound over the *column* pointer: exact when the
-    index structure is concrete; from the tuned geometry under trace; 0
-    (always-correct full sweep) otherwise."""
-    ip = m.indptr
-    if not isinstance(ip, jax.core.Tracer):
-        return _ccsk.slabs_needed(np.asarray(ip), bc, bn)
-    if tuning is not None and tuning.slabs_per_block is not None:
-        return int(tuning.slabs_per_block)
-    return 0
-
-
-def spmv_ccs(m: CCS, x: jax.Array, interpret: Optional[bool] = None,
-             tuning: Optional[TileGeometry] = None) -> jax.Array:
-    """CCS through the native column-segmented kernel.  ``block_rows`` is
-    the segmented-axis tile, so for CCS it tiles *columns* (the kernel's
-    ``block_cols``) — one knob, one meaning: rows for CSR, columns here."""
-    bc = _geom(tuning, "block_rows", min(256, _align8(m.n_cols)),
-               cap=_align8(m.n_cols))
-    bn = _geom(tuning, "block_nnz", min(2048, _align8(m.nnz_pad)),
-               cap=_align8(m.nnz_pad))
-    spb = _ccs_slab_bound(m, bc, bn, tuning)
-    y = _ccsk.ccs_spmv(jnp.asarray(m.data), jnp.asarray(m.rows),
-                       jnp.asarray(m.indptr), x, n_rows=m.n_rows,
-                       block_cols=bc, block_nnz=bn, slabs_per_block=spb,
-                       interpret=_interpret(interpret))
-    return y.astype(jnp.result_type(m.data.dtype, x.dtype))
-
-
-def spmm_ccs(m: CCS, x: jax.Array, interpret: Optional[bool] = None,
-             tuning: Optional[TileGeometry] = None) -> jax.Array:
-    k = x.shape[1]
-    bc = _geom(tuning, "block_rows", min(256, _align8(m.n_cols)),
-               cap=_align8(m.n_cols))
-    bn = _geom(tuning, "block_nnz", min(2048, _align8(m.nnz_pad)),
-               cap=_align8(m.nnz_pad))
-    bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
-    spb = _ccs_slab_bound(m, bc, bn, tuning)
-    xp = _pad_to(x, 1, bk)
-    y = _ccsk.ccs_spmm(jnp.asarray(m.data), jnp.asarray(m.rows),
-                       jnp.asarray(m.indptr), xp, n_rows=m.n_rows,
-                       block_cols=bc, block_nnz=bn, block_k=bk,
-                       slabs_per_block=spb, interpret=_interpret(interpret))
-    return y[:, :k].astype(jnp.result_type(m.data.dtype, x.dtype))
-
-
-# ---------------------------------------------------------------------------
-# BCSR — block-tiled kernel (kernels/bcsr_spmv.py)
-# ---------------------------------------------------------------------------
-def _bcsr_geometry(m: BCSR, tuning: Optional[TileGeometry]):
-    rpt = max(1, min(_geom(tuning, "block_rows", min(32, m.n_block_rows or 1)),
-                     m.n_block_rows or 1))
-    bnb = max(1, min(_geom(tuning, "block_nnz",
-                           min(512, _align8(m.nblocks_pad))),
-                     _align8(m.nblocks_pad)))
-    ip = m.indptr
-    if not isinstance(ip, jax.core.Tracer):
-        spb = _bcsr.slabs_needed(np.asarray(ip), rpt, bnb)
-    elif tuning is not None and tuning.slabs_per_block is not None:
-        spb = int(tuning.slabs_per_block)
-    else:
-        spb = 0
-    return rpt, bnb, spb
-
-
-def exact_slab_bound(m, tuning: Optional[TileGeometry] = None) -> int:
-    """Concrete slab-coverage bound for a CSR/BCSR instance at the
-    wrapper's own *effective* launch geometry (tile knobs get clamped to
-    the instance, so the bound must be derived post-clamp).  For baking
-    one bound into a geometry shared by sibling blocks, take the max over
-    the blocks — a larger bound only adds masked slabs, never drops
-    entries."""
-    t = tuning.without_slab_bound() if tuning is not None else None
-    if isinstance(m, CSR):
-        br = _geom(t, "block_rows", min(256, _align8(m.n_rows)),
-                   cap=_align8(m.n_rows))
-        bn = _geom(t, "block_nnz", min(2048, _align8(m.nnz_pad)),
-                   cap=_align8(m.nnz_pad))
-        return _csr.slabs_needed(np.asarray(m.indptr), br, bn)
-    if isinstance(m, CCS):
-        bc = _geom(t, "block_rows", min(256, _align8(m.n_cols)),
-                   cap=_align8(m.n_cols))
-        bn = _geom(t, "block_nnz", min(2048, _align8(m.nnz_pad)),
-                   cap=_align8(m.nnz_pad))
-        return _ccsk.slabs_needed(np.asarray(m.indptr), bc, bn)
-    if isinstance(m, BCSR):
-        return _bcsr_geometry(m, t)[2]
-    raise TypeError(f"no slab-coverage bound for {type(m)}")
-
-
-def spmv_bcsr(m: BCSR, x: jax.Array, interpret: Optional[bool] = None,
-              tuning: Optional[TileGeometry] = None) -> jax.Array:
-    rpt, bnb, spb = _bcsr_geometry(m, tuning)
-    xp = _pad_to(x, 0, m.block)
-    y = _bcsr.bcsr_spmv(jnp.asarray(m.data), jnp.asarray(m.block_cols),
-                        jnp.asarray(m.indptr), xp, rows_per_tile=rpt,
-                        block_nnz=bnb, slabs_per_block=spb,
-                        interpret=_interpret(interpret))
-    return y[: m.n_rows].astype(jnp.result_type(m.data.dtype, x.dtype))
-
-
-def spmm_bcsr(m: BCSR, x: jax.Array, interpret: Optional[bool] = None,
-              tuning: Optional[TileGeometry] = None) -> jax.Array:
-    k = x.shape[1]
-    rpt, bnb, spb = _bcsr_geometry(m, tuning)
-    bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
-    xp = _pad_to(_pad_to(x, 0, m.block), 1, bk)
-    y = _bcsr.bcsr_spmm(jnp.asarray(m.data), jnp.asarray(m.block_cols),
-                        jnp.asarray(m.indptr), xp, rows_per_tile=rpt,
-                        block_nnz=bnb, block_k=bk, slabs_per_block=spb,
-                        interpret=_interpret(interpret))
-    return y[: m.n_rows, :k].astype(jnp.result_type(m.data.dtype, x.dtype))
+    xg_t = _gather_rhs(_pad_to(x, 1, bk), jnp.asarray(m.cols), axis=0)
+    y_t = _csr_launch(m, xg_t, bk, interpret, tuning)
+    return y_t[:k, :m.n_rows].T.astype(
+        jnp.result_type(m.data.dtype, x.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -444,8 +310,7 @@ def spmv_sell(m: BucketedELL, x: jax.Array,
     perm = jnp.asarray(m.perm)
     y = jnp.zeros((m.n_rows,), x.dtype)
     for off, b, g in zip(m.row_offsets, m.buckets, _sell_tunings(m, tuning)):
-        yb = ell_spmv_raw(jnp.asarray(b.data), jnp.asarray(b.cols), x,
-                          interpret, g)
+        yb = spmv_ell(b, x, interpret, g)
         y = y.at[perm[off:off + b.n_rows]].set(yb.astype(y.dtype))
     return y
 
@@ -456,8 +321,7 @@ def spmm_sell(m: BucketedELL, x: jax.Array,
     perm = jnp.asarray(m.perm)
     y = jnp.zeros((m.n_rows, x.shape[1]), x.dtype)
     for off, b, g in zip(m.row_offsets, m.buckets, _sell_tunings(m, tuning)):
-        yb = ell_spmm_raw(jnp.asarray(b.data), jnp.asarray(b.cols), x,
-                          interpret, g)
+        yb = spmm_ell(b, x, interpret, g)
         y = y.at[perm[off:off + b.n_rows]].set(yb.astype(y.dtype))
     return y
 
@@ -499,13 +363,9 @@ def spmm_hybrid(m, x: jax.Array,
 # ---------------------------------------------------------------------------
 for _fmt, _spmv_fn, _spmm_fn in (
     ("csr", spmv_csr, spmm_csr),
-    ("ccs", spmv_ccs, spmm_ccs),
-    ("coo_row", spmv_coo, spmm_coo),
-    ("coo_col", spmv_coo, spmm_coo),
     ("ell_row", spmv_ell, spmm_ell),
     ("ell_col", spmv_ell, spmm_ell),
     ("sell", spmv_sell, spmm_sell),
-    ("bcsr", spmv_bcsr, spmm_bcsr),
     ("hybrid", spmv_hybrid, spmm_hybrid),
 ):
     _dispatch.register_impl(_fmt, "spmv", _spmv_fn, tier="kernel")
@@ -523,11 +383,8 @@ def __getattr__(name: str):
         return _dispatch.impl_table("spmm", "kernel")
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
-__all__ = ["ell_spmv_raw", "ell_spmm_raw", "coo_spmv_raw", "coo_spmm_raw",
-           "ell_spmv_ad", "spmv_ell", "spmm_ell", "spmv_coo", "spmm_coo",
-           "spmv_csr", "spmm_csr", "spmv_csr_via_coo", "spmm_csr_via_coo",
-           "spmv_ccs", "spmm_ccs",
-           "spmv_bcsr", "spmm_bcsr", "exact_slab_bound",
-           "spmv_sell", "spmm_sell",
+__all__ = ["ell_spmv_raw", "ell_spmm_raw", "ell_spmv_ad",
+           "spmv_ell", "spmm_ell", "spmv_csr", "spmm_csr",
+           "exact_slab_bound", "spmv_sell", "spmm_sell",
            "spmv_hybrid", "spmm_hybrid", "KERNEL_SPMV_IMPLS",
            "KERNEL_SPMM_IMPLS"]

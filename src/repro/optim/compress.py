@@ -54,8 +54,6 @@ def make_compressed_allreduce(mesh, axis: str = "data"):
     """Top-level helper: (grads, error) -> (mean grads, error).  Both trees
     carry a leading worker dim sharded over ``axis`` (per-worker gradients
     and per-worker error-feedback residuals)."""
-    from jax.experimental.shard_map import shard_map
-
     def fn(grads_stacked, error_stacked):
         def inner(g, e):
             g_local = jax.tree.map(lambda a: a[0], g)   # drop worker dim
@@ -65,7 +63,7 @@ def make_compressed_allreduce(mesh, axis: str = "data"):
                     jax.tree.map(lambda a: a[None], ne))
         spec_g = jax.tree.map(lambda _: P(axis), grads_stacked)
         spec_e = jax.tree.map(lambda _: P(axis), error_stacked)
-        return shard_map(inner, mesh=mesh,
+        return jax.shard_map(inner, mesh=mesh,
                          in_specs=(spec_g, spec_e),
                          out_specs=(spec_g, spec_e))(grads_stacked,
                                                      error_stacked)

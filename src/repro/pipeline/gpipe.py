@@ -17,7 +17,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -35,11 +34,8 @@ def pipeline_forward(stage_params: Any, x_micro: jax.Array, *,
         params1 = jax.tree.map(lambda a: a[0], params_local)
         stage = jax.lax.axis_index(axis)
         ticks = M + n_stages - 1
-        # mark carries as device-varying over the pipe axis (shard_map vma;
-        # jax < 0.5 has no pcast and no vma tracking — replication is fine)
-        pcast = getattr(jax.lax, "pcast", None)
-        vary = (lambda v: pcast(v, (axis,), to="varying")) if pcast \
-            else (lambda v: v)
+        # mark carries as device-varying over the pipe axis (shard_map vma)
+        vary = lambda v: jax.lax.pcast(v, (axis,), to="varying")  # noqa: E731
         buf = vary(jnp.zeros_like(xs[0]))
         outs = vary(jnp.zeros_like(xs))
 
@@ -69,7 +65,7 @@ def pipeline_forward(stage_params: Any, x_micro: jax.Array, *,
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(per_device, mesh=mesh,
+    return jax.shard_map(per_device, mesh=mesh,
                      in_specs=(pspec, P()), out_specs=P())(
         stage_params, x_micro)
 

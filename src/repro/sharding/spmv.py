@@ -138,8 +138,6 @@ def _make_shard_map_fns(stacked, axis: str, mesh, axis_name: str,
     data_s = jax.device_put(jnp.asarray(data_s), sharded)
     cols_s = jax.device_put(jnp.asarray(cols_s), sharded)
     ip_s = jax.device_put(jnp.asarray(ip_s), sharded)
-    from jax.experimental.shard_map import shard_map
-
     if axis == "row":
         rows_per = np.diff(boundaries)
 
@@ -149,7 +147,7 @@ def _make_shard_map_fns(stacked, axis: str, mesh, axis_name: str,
                             shape=(rows_pad, n_cols), nnz=nnz_pad)
                 return op(local, xx)
 
-            out = shard_map(
+            out = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis_name), P(axis_name), P(axis_name), P()),
                 out_specs=P(axis_name))(data_s, cols_s, ip_s, x)
@@ -174,7 +172,7 @@ def _make_shard_map_fns(stacked, axis: str, mesh, axis_name: str,
                             shape=(n_rows, width_pad), nnz=nnz_pad)
                 return jax.lax.psum(op(local, xl), axis_name)
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis_name), P(axis_name), P(axis_name),
                           P(axis_name), P()),

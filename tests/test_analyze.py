@@ -57,7 +57,8 @@ def test_slab_bound_below_structure(good):
 
 
 def test_vmem_over_budget_and_override(good):
-    good["geometry"]["spmv"]["block_nnz"] = 2 ** 23   # ~64 MiB of slab
+    # a 16384 x 256 one-hot row-recovery tile: ~32 MiB
+    good["geometry"]["spmv"]["block_nnz"] = 2 ** 14
     good["geometry"]["spmv"]["slabs_per_block"] = 1
     assert "RPL004" in rules(lint_plan(good), "error")
     # a bigger part makes the same geometry feasible

@@ -8,8 +8,7 @@ from repro.core import dispatch
 from repro.core.autotune import TuningDB
 from repro.core.kernel_tune import (GeometryRecord, KernelTuner, TileGeometry,
                                     candidate_geometries, nearest_geometry)
-from repro.core.transform import (csr_from_dense, host_csr_to_bcsr,
-                                  host_csr_to_coo_row, host_csr_to_ell)
+from repro.core.transform import csr_from_dense, host_csr_to_ell
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +42,7 @@ def fake_timer(prefer_rows=32, prefer_nnz=1024):
 # candidate grids
 # ---------------------------------------------------------------------------
 def test_candidates_bounded_and_deduped():
-    for fmt in ("ell_row", "coo_row", "csr", "ccs", "bcsr", "sell"):
+    for fmt in ("ell_row", "csr", "sell"):
         for op in ("spmv", "spmm"):
             cands = candidate_geometries(fmt, op, n_rows=150, width=20,
                                          nnz_pad=1800, batch=16)
@@ -52,7 +51,8 @@ def test_candidates_bounded_and_deduped():
                     for g in cands]
             assert len(keys) == len(set(keys)), (fmt, op)
     # formats without a tunable kernel stay out of the search
-    assert candidate_geometries("hybrid", "spmv") == []
+    for fmt in ("hybrid", "coo_row", "ccs", "bcsr"):
+        assert candidate_geometries(fmt, "spmv") == []
 
 
 def test_candidates_clamped_to_profile():
@@ -67,10 +67,8 @@ def test_candidates_clamped_to_profile():
 @pytest.mark.slow
 @pytest.mark.parametrize("transform,fmt", [
     (lambda m: m, "csr"),
-    (host_csr_to_coo_row, "coo_row"),
     (host_csr_to_ell, "ell_row"),
-    (lambda m: host_csr_to_bcsr(m, block=8), "bcsr"),
-], ids=["csr", "coo_row", "ell_row", "bcsr"])
+], ids=["csr", "ell_row"])
 def test_tune_is_deterministic_with_fake_timer(problem, transform, fmt):
     _, m = problem
     obj = transform(m)
@@ -103,25 +101,6 @@ def test_csr_winner_carries_exact_slab_bound(problem):
                                              g.block_nnz)
 
 
-def test_ccs_tunes_like_every_other_format(problem):
-    """CCS has a native kernel + candidate grid: the tuner searches it,
-    the winner carries the exact column-pointer slab bound, and the
-    geometry round-trips through the db."""
-    from repro.core.transform import host_csr_to_ccs
-    from repro.kernels.csr_spmv import slabs_needed
-    _, m = problem
-    ccs = host_csr_to_ccs(m)
-    db = TuningDB(machine="t", c=1.0, records=[], d_star={})
-    tuner = KernelTuner(db=db, timer=fake_timer(), interpret=True)
-    rec = tuner.tune(ccs)
-    assert rec.fmt == "ccs" and rec.speedup >= 1.0
-    g = rec.geometry
-    assert g.slabs_per_block == slabs_needed(ccs.indptr, g.block_rows,
-                                             g.block_nnz)
-    db2 = TuningDB.from_json(db.to_json())
-    assert KernelTuner(db=db2).best(ccs) == g
-
-
 @pytest.mark.slow
 def test_force_retune_replaces_record_in_place(problem):
     """force=True supersedes the memoized record instead of appending a
@@ -129,21 +108,22 @@ def test_force_retune_replaces_record_in_place(problem):
     and nearest_geometry can never resurrect the stale loser."""
     _, m = problem
     db = TuningDB(machine="t", c=1.0, records=[], d_star={})
-    tuner = KernelTuner(db=db, timer=fake_timer(prefer_rows=64),
+    tuner = KernelTuner(db=db, timer=fake_timer(prefer_rows=128),
                         interpret=True)
     r1 = tuner.tune(m)
-    assert r1.geometry.block_rows == 64
+    assert r1.geometry.block_rows == 128
     # the machine "changed its mind": re-tune now prefers a different tile
-    tuner._timer = fake_timer(prefer_rows=128)
+    # (the whole-matrix row tile: 150 rows, 8-aligned)
+    tuner._timer = fake_timer(prefer_rows=152)
     r2 = tuner.tune(m, force=True)
-    assert r2.geometry.block_rows == 128
+    assert r2.geometry.block_rows == 152
     assert len(db.geometries) == 1, "re-tune must not accumulate duplicates"
     db2 = TuningDB.from_json(db.to_json())
     assert len(db2.geometries) == 1
     assert db2.geometries[0].geometry == r2.geometry
     # the NN fallback sees only the fresh winner
     assert (nearest_geometry(db2.geometries, "csr", "spmv",
-                             d_mat=r2.d_mat).block_rows == 128)
+                             d_mat=r2.d_mat).block_rows == 152)
 
 
 # ---------------------------------------------------------------------------

@@ -7,9 +7,7 @@ import jax.numpy as jnp
 pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (csr_from_dense, host_csr_to_coo_col,
-                        host_csr_to_coo_row, host_csr_to_ell,
-                        host_csr_to_sell)
+from repro.core import csr_from_dense, host_csr_to_ell, host_csr_to_sell
 from repro.kernels import ops, ref
 
 
@@ -71,41 +69,14 @@ def test_ell_spmm_kernel(rng, n_rows, width, n_cols, k):
 
 
 # ---------------------------------------------------------------------------
-# COO SpMV: sorted + unsorted rows, duplicates allowed
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("nnz,n_rows,n_cols,sort", [
-    (4096, 128, 128, True),
-    (1000, 64, 256, False),
-    (8, 8, 8, True),
-    (9000, 333, 77, False),
-])
-def test_coo_spmv_kernel(rng, nnz, n_rows, n_cols, sort):
-    rows = rng.integers(0, n_rows, nnz).astype(np.int32)
-    if sort:
-        rows = np.sort(rows)
-    cols = rng.integers(0, n_cols, nnz).astype(np.int32)
-    data = rng.normal(size=nnz).astype(np.float32)
-    x = rng.normal(size=n_cols).astype(np.float32)
-    got = ops.coo_spmv_raw(jnp.asarray(data), jnp.asarray(rows),
-                           jnp.asarray(cols), jnp.asarray(x), n_rows,
-                           interpret=True)
-    want = ref.coo_spmv_ref(jnp.asarray(data), jnp.asarray(rows),
-                            jnp.asarray(cols), jnp.asarray(x), n_rows)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
 # format-level kernels vs dense oracle (all formats through one matrix)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("impl,transform", [
     (ops.spmv_csr, lambda m: m),
-    (ops.spmv_coo, host_csr_to_coo_row),
-    (ops.spmv_coo, host_csr_to_coo_col),
     (ops.spmv_ell, host_csr_to_ell),
     (ops.spmv_ell, lambda m: host_csr_to_ell(m, order="col")),
     (ops.spmv_sell, host_csr_to_sell),
-], ids=["csr", "coo_row", "coo_col", "ell_row", "ell_col", "sell"])
+], ids=["csr", "ell_row", "ell_col", "sell"])
 def test_format_kernels_vs_dense(rng, impl, transform):
     dense = random_dense(rng, 200, 150, 0.08)
     m = transform(csr_from_dense(dense, pad=8))

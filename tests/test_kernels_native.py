@@ -1,15 +1,13 @@
-"""Native row-segmented CSR, column-segmented CCS and block-tiled BCSR
-Pallas kernels vs the dense oracle: SpMV + SpMM for B in {1, 3, 128},
-ragged shapes, geometry sweeps, and the traced (full-sweep / tuned-bound)
-launch modes."""
+"""The native row-segmented CSR Pallas kernel vs the dense oracle: SpMV +
+SpMM for B in {1, 3, 128}, ragged shapes, geometry sweeps, and the traced
+(full-sweep / tuned-bound) launch modes."""
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
 from repro.core.kernel_tune import TileGeometry
-from repro.core.transform import (csr_from_dense, host_csr_to_bcsr,
-                                  host_csr_to_ccs)
+from repro.core.transform import csr_from_dense
 from repro.kernels import ops
 from repro.kernels.csr_spmv import slabs_needed
 
@@ -113,14 +111,13 @@ def test_csr_big_matrix_geometry_on_tiny_matrix(rng, monkeypatch):
     X = rng.normal(size=(16, 3)).astype(np.float32)
     big = TileGeometry(block_rows=512, block_nnz=65536)
     seen = []
-    for name in ("csr_spmv", "csr_spmm"):
-        orig = getattr(ops._csr, name)
+    orig = ops._csr.csr_spmm_t
 
-        def spy(*args, _orig=orig, **kw):
-            seen.append(kw["block_nnz"])
-            return _orig(*args, **kw)
+    def spy(*args, **kw):
+        seen.append(kw["block_nnz"])
+        return orig(*args, **kw)
 
-        monkeypatch.setattr(ops._csr, name, spy)
+    monkeypatch.setattr(ops._csr, "csr_spmm_t", spy)
     got = ops.spmv_csr(m, jnp.asarray(x), interpret=True, tuning=big)
     np.testing.assert_allclose(np.asarray(got), dense @ x, **TOL)
     gotm = ops.spmm_csr(m, jnp.asarray(X), interpret=True, tuning=big)
@@ -137,146 +134,20 @@ def test_slabs_needed_exact(rng):
 
 
 # ---------------------------------------------------------------------------
-# BCSR block-tiled kernel
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("n_rows,n_cols,density,block", [
-    (256, 256, 0.05, 8),
-    (100, 61, 0.2, 8),      # ragged: rows/cols not multiples of b
-    (80, 48, 0.3, 4),       # small blocks
-])
-def test_bcsr_spmv_vs_dense(rng, n_rows, n_cols, density, block):
-    dense = random_dense(rng, n_rows, n_cols, density)
-    m = host_csr_to_bcsr(csr_from_dense(dense, pad=8), block=block)
-    x = rng.normal(size=n_cols).astype(np.float32)
-    got = ops.spmv_bcsr(m, jnp.asarray(x), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), dense @ x, **TOL)
-
-
-@pytest.mark.parametrize("batch", [1, 3, 128])
-def test_bcsr_spmm_vs_dense(rng, batch):
-    dense = random_dense(rng, 120, 90, 0.1)
-    m = host_csr_to_bcsr(csr_from_dense(dense, pad=8), block=8)
-    X = rng.normal(size=(90, batch)).astype(np.float32)
-    got = ops.spmm_bcsr(m, jnp.asarray(X), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), dense @ X, **TOL)
-
-
-@pytest.mark.parametrize("g", [
-    TileGeometry(block_rows=8, block_nnz=128),
-    TileGeometry(block_rows=64, block_nnz=2048, block_k=8),
-], ids=["small", "large"])
-def test_bcsr_geometry_sweep(rng, g):
-    dense = random_dense(rng, 96, 72, 0.2)
-    m = host_csr_to_bcsr(csr_from_dense(dense, pad=8), block=8)
-    x = rng.normal(size=72).astype(np.float32)
-    X = rng.normal(size=(72, 3)).astype(np.float32)
-    got = ops.spmv_bcsr(m, jnp.asarray(x), interpret=True, tuning=g)
-    np.testing.assert_allclose(np.asarray(got), dense @ x, **TOL)
-    gotm = ops.spmm_bcsr(m, jnp.asarray(X), interpret=True, tuning=g)
-    np.testing.assert_allclose(np.asarray(gotm), dense @ X, **TOL)
-
-
-def test_bcsr_traced(rng):
-    dense = random_dense(rng, 64, 64, 0.1)
-    m = host_csr_to_bcsr(csr_from_dense(dense, pad=8), block=8)
-    x = jnp.asarray(rng.normal(size=64).astype(np.float32))
-    y = jax.jit(lambda mm, v: ops.spmv_bcsr(mm, v, interpret=True))(m, x)
-    np.testing.assert_allclose(np.asarray(y), dense @ np.asarray(x), **TOL)
-
-
-# ---------------------------------------------------------------------------
-# CCS column-segmented kernel (the paper's Phase-I format, last to go native)
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("n_rows,n_cols,density", [
-    (256, 256, 0.05),    # aligned
-    (100, 61, 0.2),      # ragged, denser
-    (37, 513, 0.02),     # wide: many column blocks
-    (8, 8, 0.5),         # minimum tile
-])
-def test_ccs_spmv_vs_dense(rng, n_rows, n_cols, density):
-    dense = random_dense(rng, n_rows, n_cols, density)
-    m = host_csr_to_ccs(csr_from_dense(dense, pad=8))
-    x = rng.normal(size=n_cols).astype(np.float32)
-    got = ops.spmv_ccs(m, jnp.asarray(x), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), dense @ x, **TOL)
-
-
-@pytest.mark.parametrize("batch", [1, 3, 128])
-def test_ccs_spmm_vs_dense(rng, batch):
-    dense = random_dense(rng, 150, 90, 0.1)
-    m = host_csr_to_ccs(csr_from_dense(dense, pad=8))
-    X = rng.normal(size=(90, batch)).astype(np.float32)
-    got = ops.spmm_ccs(m, jnp.asarray(X), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), dense @ X, **TOL)
-
-
-@pytest.mark.parametrize("g", [
-    TileGeometry(block_rows=8, block_nnz=1024),
-    TileGeometry(block_rows=64, block_nnz=1024),
-    TileGeometry(block_rows=512, block_nnz=8192),
-    TileGeometry(block_rows=32, block_nnz=64, block_k=8),
-], ids=["c8", "c64", "c512-bn8192", "spmm-k8"])
-def test_ccs_geometry_sweep(rng, g):
-    dense = random_dense(rng, 120, 200, 0.15)
-    m = host_csr_to_ccs(csr_from_dense(dense, pad=8))
-    x = rng.normal(size=200).astype(np.float32)
-    X = rng.normal(size=(200, 5)).astype(np.float32)
-    got = ops.spmv_ccs(m, jnp.asarray(x), interpret=True, tuning=g)
-    np.testing.assert_allclose(np.asarray(got), dense @ x, **TOL)
-    gotm = ops.spmm_ccs(m, jnp.asarray(X), interpret=True, tuning=g)
-    np.testing.assert_allclose(np.asarray(gotm), dense @ X, **TOL)
-
-
-def test_ccs_traced_full_sweep_and_tuned_bound(rng):
-    """Under jit the column pointer is abstract: with no geometry the
-    kernel takes the always-correct full slab sweep; a tuned geometry
-    carries the exact static slab bound into the trace."""
-    dense = random_dense(rng, 80, 120, 0.1)
-    m = host_csr_to_ccs(csr_from_dense(dense, pad=8))
-    x = jnp.asarray(rng.normal(size=120).astype(np.float32))
-    y0 = jax.jit(lambda mm, v: ops.spmv_ccs(mm, v, interpret=True))(m, x)
-    np.testing.assert_allclose(np.asarray(y0), dense @ np.asarray(x), **TOL)
-    g = TileGeometry(block_rows=32, block_nnz=512,
-                     slabs_per_block=slabs_needed(m.indptr, 32, 512))
-    y1 = jax.jit(lambda mm, v: ops.spmv_ccs(mm, v, interpret=True,
-                                            tuning=g))(m, x)
-    np.testing.assert_allclose(np.asarray(y1), dense @ np.asarray(x), **TOL)
-
-
-def test_ccs_heavy_tail_and_empty_columns(rng):
-    """A few dense columns plus entirely empty columns (the transpose of
-    the memplus/torso row pathology) still fit the per-column-block slab
-    coverage, and empty columns contribute exactly nothing."""
-    n_rows, n_cols = 200, 128
-    dense = np.zeros((n_rows, n_cols), np.float32)
-    dense[:, 5] = rng.normal(size=n_rows)            # one dense column
-    dense[:150, 70] = rng.normal(size=150)
-    mask = rng.random((n_rows, n_cols)) < 0.01       # sparse elsewhere
-    dense += mask * rng.normal(size=dense.shape).astype(np.float32)
-    dense[:, 30:40] = 0.0                            # a run of empty columns
-    m = host_csr_to_ccs(csr_from_dense(dense.astype(np.float32), pad=8))
-    assert (np.diff(np.asarray(m.indptr))[30:40] == 0).all()
-    x = rng.normal(size=n_cols).astype(np.float32)
-    got = ops.spmv_ccs(m, jnp.asarray(x), interpret=True,
-                       tuning=TileGeometry(block_rows=32, block_nnz=64))
-    np.testing.assert_allclose(np.asarray(got), dense @ x, **TOL)
-    X = rng.normal(size=(n_cols, 3)).astype(np.float32)
-    gotm = ops.spmm_ccs(m, jnp.asarray(X), interpret=True,
-                        tuning=TileGeometry(block_rows=32, block_nnz=64))
-    np.testing.assert_allclose(np.asarray(gotm), dense @ X, **TOL)
-
-
-# ---------------------------------------------------------------------------
 # the registry serves the native kernels (no COO detour, no reference CCS)
 # ---------------------------------------------------------------------------
 def test_registry_serves_native_csr_ccs_and_bcsr():
+    """CSR is served by its native kernel; CCS and BCSR have no kernel the
+    TPU compiler accepts, so they resolve to the reference tier and say
+    so."""
     from repro.core import dispatch
     assert dispatch.get_impl("csr", "spmv", tier="kernel") is ops.spmv_csr
     assert dispatch.get_impl("csr", "spmm", tier="kernel") is ops.spmm_csr
-    assert dispatch.get_impl("ccs", "spmv", tier="kernel") is ops.spmv_ccs
-    assert dispatch.get_impl("ccs", "spmm", tier="kernel") is ops.spmm_ccs
-    assert dispatch.get_impl("bcsr", "spmv", tier="kernel") is ops.spmv_bcsr
-    assert dispatch.get_impl("bcsr", "spmm", tier="kernel") is ops.spmm_bcsr
+    for fmt in ("ccs", "bcsr"):
+        for op in ("spmv", "spmm"):
+            assert not dispatch.has_impl(fmt, op, tier="kernel")
+            assert dispatch.resolve_impl(fmt, op, tier="kernel")[1] \
+                == "reference"
 
 
 def test_block_sizes_covers_narrow_band_tightly():

@@ -472,7 +472,7 @@ def test_kernel_tier_plan_via_dispatch_formats(problem):
     _, csr = problem
     fmts = [f for f in dispatch.registered_formats("spmv", tier="kernel")
             if f != "hybrid"]
-    assert {"csr", "ccs", "sell", "bcsr"} <= set(fmts)
+    assert {"csr", "ell_row", "ell_col", "sell"} <= set(fmts)
     for f in fmts:
         plan = Planner().plan(csr, fmt=f)
         assert plan.transform.name == f

@@ -4,21 +4,29 @@
     python chip_smoke.py               # one chip: torso1 and xenon2
     python chip_smoke.py --chips 4     # the sharded path on four chips
 
+First the paper's off-line phase runs on this device: the Table-1 suite
+(torso1 left out, as in the paper) is synthesized at a small scale from
+``--seed``, each matrix is timed in CRS and in ELL through the kernel
+tier, and the D_mat threshold D* is learned into a ``TuningDB``.
+
 One chip.  ``torso1`` (116,158 rows, 8.5M nonzeros, D_mat 5.72) and
 ``xenon2`` (157,464 rows, 3.87M nonzeros, D_mat 0.16) are synthesized at
 scale 1.0 from ``--seed`` (``repro.core.suite``).  Each is
 
-  * planned by ``Planner`` on the kernel tier with a ``KernelTuner``
-    (the paper's CRS-vs-ELL decision: torso1 stays in CSR, xenon2 goes to
-    ELL), bound, and multiplied: ``P @ x`` and ``P @ X`` with B = 32;
+  * planned by ``Planner`` with the paper's rule (transform to ELL iff
+    D_mat < D*) on the kernel tier with a ``KernelTuner``, bound, and
+    multiplied: ``P @ x`` and ``P @ X`` with B = 32.  torso1's D_mat is
+    above every suite matrix's, so it stays in CSR; xenon2 goes to ELL
+    when the measured D* is above 0.16;
   * registered in ``SpMVService(tuner=KernelTuner(...))`` and served: a
     few ``spmv`` calls, one ``spmm`` at B = 32 and one ``submit``/``flush``
-    batch.
+    batch.  The service builds a hybrid matrix whose row blocks take
+    their formats from its cost model.
 
 Four chips (``--chips 4``).  torso1 only: ``Planner.plan_sharded`` with 4
-shards on the row and the column axis, bound in ``shard_map`` and
-``dispatch`` modes, and served by ``SpMVService`` from a sharded plan.
-Each result is compared with the one-chip result as well.
+shards on the row and the column axis under the same rule, bound in
+``shard_map`` and ``dispatch`` modes, and served by ``SpMVService`` from a
+sharded plan.  Each result is compared with the one-chip result as well.
 
 Every result is checked against an independent float64 CSR product on
 the host, row by row:
@@ -26,9 +34,10 @@ the host, row by row:
     |y_i - yref_i| <= 1e-4 * sum_j |a_ij * x_j|
 
 The run also fails when anything falls back: a registration that
-degrades, a guard that serves below its tuned rung, a plan whose ops do
-not resolve to the kernel tier, a block format without a kernel, or
-Pallas kernels that would run in interpret mode.  The last line of stdout
+degrades, a guard that serves below its tuned rung (the service's, or a
+shard's own in dispatch mode), a plan whose ops do not resolve to the
+kernel tier, a block format without a kernel, or Pallas kernels that
+would run in interpret mode.  The last line of stdout
 is ``{"ok": true, "device": {...}}``; on any failure, or on a platform
 other than ``tpu``, the script exits non-zero and prints no such line.
 """
@@ -39,7 +48,7 @@ import json
 import os
 import sys
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -52,6 +61,8 @@ BATCH = 32
 MATRICES = ("torso1", "xenon2")
 #: the paper's decision space: keep CRS, or transform to ELL
 PLAN_FORMATS = ("ell_row",)
+#: the off-line phase's suite scale: N and NNZ shrink, D_mat does not
+OFFLINE_SCALE = 0.02
 EXPECTED_ITERATIONS = 1000
 #: tuner candidates timed per (format, op) beyond the default launch:
 #: enough to exercise the sweep, few enough for a cold run of minutes
@@ -147,14 +158,37 @@ def new_tuner(max_candidates: Optional[int]):
     return KernelTuner(db=db, max_candidates=max_candidates)
 
 
+def offline_db(seed: int, scale: float = OFFLINE_SCALE,
+               include: Optional[Sequence[str]] = None):
+    """The paper's off-line phase on this device: the Table-1 suite
+    without torso1 (or the ``include`` subset of it), CRS and ELL timed
+    through the kernel tier, D* learned at c = 1.  Each launch is tuned
+    before it is timed (the default and one candidate), so the timed
+    launches are those the planner would bind."""
+    from repro.core.autotune import offline_phase
+    from repro.core.suite import paper_suite
+    from repro.kernels import ops
+    t0 = time.perf_counter()
+    suite = paper_suite(scale=scale, seed=seed, include=include,
+                        skip_ell_overflow=True)
+    db = offline_phase(suite, formats=PLAN_FORMATS, machine="chip_smoke",
+                       spmv_impls={"csr": ops.spmv_csr,
+                                   "ell_row": ops.spmv_ell},
+                       tuner=new_tuner(1))
+    emit("offline", matrices=len(suite), scale=scale,
+         d_star=db.d_star, offline_s=time.perf_counter() - t0)
+    return db
+
+
 # ---------------------------------------------------------------------------
 # phases
 # ---------------------------------------------------------------------------
-def plan_phase(name: str, csr, x, X, refs, max_candidates) -> Dict:
-    """Planner -> ExecutionPlan -> transform -> Pallas kernel."""
+def plan_phase(name: str, csr, x, X, refs, db, max_candidates) -> Dict:
+    """D_mat -> the paper's rule -> ExecutionPlan -> transform -> Pallas
+    kernel."""
     from repro.core.plan import Planner
-    planner = Planner(tuner=new_tuner(max_candidates), tier="kernel",
-                      rule="cost_model")
+    planner = Planner(db=db, tuner=new_tuner(max_candidates), tier="kernel",
+                      rule="paper")
     t0 = time.perf_counter()
     plan = planner.plan(csr, batch=BATCH,
                         expected_iterations=EXPECTED_ITERATIONS,
@@ -169,13 +203,46 @@ def plan_phase(name: str, csr, x, X, refs, max_candidates) -> Dict:
     Y, t_m = _timed(P.__matmul__, X)
     worst = max(check(f"plan {name} spmv", y, *refs["x"]),
                 check(f"plan {name} spmm", Y, *refs["X"]))
-    out = dict(matrix=name, fmt=plan.fmt, tiers=P.tiers, served_by="plan",
+    out = dict(matrix=name, d_mat=plan.d_mat, d_star=plan.d_star,
+               fmt=plan.fmt, tiers=P.tiers, served_by="plan",
                plan_s=plan_s,
                compile_s=(t_first_v - t_v) + (t_first_m - t_m),
                spmv_ms=t_v * 1e3, spmm_ms=t_m * 1e3,
                worst_err_over_bound=worst)
     emit("plan", **out)
     return {"planned": P, "y": y, "Y": Y, **out}
+
+
+def _tuned_calls(what: str, snap: Dict[str, Any]) -> Tuple[int, int]:
+    """A guard ladder's snapshot, required to have served every call on
+    its tuned rung; returns ``(tuned, calls)``."""
+    require(snap["calls"] > 0, f"{what}: never called")
+    require(snap["fallback_calls"] == 0
+            and snap["served_by"].get("tuned", 0) == snap["calls"],
+            f"{what}: {snap['fallback_calls']} of {snap['calls']} calls "
+            f"fell back: {snap['served_by']} failures {snap['failures']}")
+    return snap["served_by"]["tuned"], snap["calls"]
+
+
+def _shard_checks(what: str, spm) -> Dict[str, str]:
+    """Dispatch mode: every shard's planned matrix resolves to the kernel
+    tier, and every shard's own ladder (its kernel, then reference CSR)
+    served every call on the kernel.  Returns ``tuned/calls`` per op,
+    summed over the shards."""
+    require(spm.mode == "dispatch", f"{what}: bound as {spm.mode}")
+    for i, pm in enumerate(spm.planned):
+        require(pm.tiers == {"spmv": "kernel", "spmm": "kernel"},
+                f"{what} shard {i}: ops resolved to tiers {pm.tiers}")
+    report = spm.guard_report()
+    require(len(report) == spm.n_shards,
+            f"{what}: {len(report)} shard guards for {spm.n_shards} shards")
+    served = {}
+    for op in ("spmv", "spmm"):
+        counts = [_tuned_calls(f"{what} shard {i} {op}", shard[op])
+                  for i, shard in enumerate(report)]
+        served[op] = (f"{sum(t for t, _ in counts)}/"
+                      f"{sum(c for _, c in counts)}")
+    return served
 
 
 def _service_checks(name: str, svc, entry, sink) -> Dict[str, Any]:
@@ -194,16 +261,17 @@ def _service_checks(name: str, svc, entry, sink) -> Dict[str, Any]:
              else {plan.tier})
     require(tiers == {"kernel"},
             f"serve {name}: plan tiers {sorted(tiers)}, not 'kernel'")
+    require(set(entry.guards) == {"spmv", "spmm"},
+            f"serve {name}: guarded ops {sorted(entry.guards)}")
     served_by = {}
     for op, g in entry.guards.items():
-        snap = g.snapshot()
-        require(snap["calls"] > 0, f"serve {name} {op}: never called")
-        require(snap["fallback_calls"] == 0
-                and snap["served_by"].get("tuned", 0) == snap["calls"],
-                f"serve {name} {op}: {snap['fallback_calls']} of "
-                f"{snap['calls']} calls fell back: {snap['served_by']} "
-                f"failures {snap['failures']}")
-        served_by[op] = f"{snap['served_by']['tuned']}/{snap['calls']}"
+        tuned, calls = _tuned_calls(f"serve {name} {op}", g.snapshot())
+        served_by[op] = f"{tuned}/{calls}"
+    if hasattr(entry.matrix, "guard_report"):
+        # a sharded entry's tuned rung runs each shard through its own
+        # ladder, which would absorb a failing shard kernel
+        for op, s in _shard_checks(f"serve {name}", entry.matrix).items():
+            served_by[f"shards_{op}"] = s
     if getattr(plan, "fmt", None) == "hybrid":
         for fmt in entry.formats():
             for op in ("spmv", "spmm"):
@@ -258,7 +326,7 @@ def serve_phase(name: str, csr, x, X, refs, max_candidates,
         served_by = _service_checks(name, svc, entry, sink)
         out = dict(matrix=name, fmt=getattr(entry.plan, "fmt", "sharded"),
                    blocks=entry.formats(),
-                   tiers={op: "kernel" for op in served_by},
+                   tiers={op: "kernel" for op in entry.guards},
                    served_by=served_by, register_s=register_s,
                    compile_s=(t_v[0] - t_v[1]) + (t_m[0] - t_m[1]),
                    spmv_ms=t_v[1] * 1e3, spmm_ms=t_m[1] * 1e3,
@@ -271,13 +339,13 @@ def serve_phase(name: str, csr, x, X, refs, max_candidates,
             obs.disable()
 
 
-def sharded_phase(name: str, csr, x, X, refs, one_chip, n_chips: int):
+def sharded_phase(name: str, csr, x, X, refs, db, one_chip, n_chips: int):
     """Row and column sharding in shard_map and dispatch modes, and a
     sharded plan served by SpMVService — against the one-chip result and
     the host reference."""
     import jax
     from repro.core.plan import Planner
-    planner = Planner(tier="kernel", rule="cost_model")
+    planner = Planner(db=db, tier="kernel", rule="paper")
     for axis in ("row", "col"):
         splan = planner.plan_sharded(csr, n_shards=n_chips, axis=axis,
                                      batch=BATCH, formats=PLAN_FORMATS,
@@ -307,18 +375,23 @@ def sharded_phase(name: str, csr, x, X, refs, one_chip, n_chips: int):
             check(what + " spmm vs one chip", Y,
                   np.asarray(one_chip["Y"], np.float64),
                   2 * refs["X"][1])
-            # shard_map runs one SPMD reference-CSR body on every device;
-            # dispatch runs each shard's own planned kernel
+            if mode == "shard_map":
+                # one SPMD reference-CSR body on every device, by design
+                body, served_by = "reference_csr", "spmd"
+            else:
+                served_by = _shard_checks(what, spm)
+                body = "kernel"
             emit("sharded", matrix=name, axis=axis, mode=mode,
-                 shards=",".join(splan.shard_formats()),
-                 body="reference_csr" if mode == "shard_map" else "kernel",
-                 devices=len(devs),
+                 shards=",".join(splan.shard_formats()), body=body,
+                 served_by=served_by, devices=len(devs),
                  compile_s=(t_first_v - t_v) + (t_first_m - t_m),
                  spmv_ms=t_v * 1e3, spmm_ms=t_m * 1e3,
                  worst_err_over_bound=worst)
         if axis == "row":
             out = serve_phase(f"{name}_sharded", csr, x, X, refs, None,
                               plan=splan, mode="dispatch")
+            require("shards_spmv" in out["served_by"],
+                    "sharded serve: shard guards were not read")
             require(out["fmt"] == "sharded",
                     f"sharded serve bound a {out['fmt']!r} plan")
 
@@ -352,6 +425,7 @@ def main(argv=None) -> int:
         require(not ops._interpret(None),
                 "Pallas kernels would run in interpret mode on this TPU")
         names = MATRICES if args.chips == 1 else ("torso1",)
+        db = offline_db(args.seed)
         for name in names:
             t0 = time.perf_counter()
             csr = synthesize(name, 1.0, args.seed)
@@ -361,11 +435,11 @@ def main(argv=None) -> int:
             emit("setup", matrix=name, n=csr.n_rows, nnz=csr.nnz,
                  setup_s=time.perf_counter() - t0)
             if args.chips == 1:
-                plan_phase(name, csr, x, X, refs, MAX_CANDIDATES)
+                plan_phase(name, csr, x, X, refs, db, MAX_CANDIDATES)
                 serve_phase(name, csr, x, X, refs, MAX_CANDIDATES)
             else:
-                one = plan_phase(name, csr, x, X, refs, 0)
-                sharded_phase(name, csr, x, X, refs, one, args.chips)
+                one = plan_phase(name, csr, x, X, refs, db, 0)
+                sharded_phase(name, csr, x, X, refs, db, one, args.chips)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -387,24 +387,26 @@ def decide_generalized(db: TuningDB, stats: MatrixStats,
 # measurement-free roofline cost model (beyond paper)
 # ---------------------------------------------------------------------------
 #: memory bandwidth per device, keyed by ``jax.Device.device_kind``, with
-#: its source.  A device kind missing here is an error, never a default.
+#: its source.  A TPU kind missing here is an error, never a default.
+#: The cost model's decisions are scale-invariant: every term of
+#: :meth:`MachineModel.t_spmv` scales with ``1 / stream_bw``, and every
+#: consumer compares ratios, so the figure sets the unit of the modelled
+#: times, never the choice.
 DEVICE_BANDWIDTH: Dict[str, Tuple[float, str]] = {
     "TPU v5 lite": (819e9, "Google Cloud TPU v5e documentation: "
                            "16 GB of HBM at 819 GB/s per chip"),
-    # The host CPU has no published figure; the cost model's decisions
-    # depend only on bandwidth ratios (every term scales with
-    # 1 / stream_bw), so CPU runs use this nominal unit and report no
-    # device time from it.
-    "cpu": (819e9, "nominal: decisions on a CPU depend only on ratios"),
 }
 
 
 def device_bandwidth(kind: Optional[str] = None) -> float:
     """Memory bandwidth (bytes/s) of ``kind``, default the first device
-    JAX reports (its ``device_kind``; the platform name for CPUs)."""
+    JAX reports.  A host CPU has no recorded figure: there the model runs
+    in a unit bandwidth of 1.0, which leaves every decision unchanged."""
     if kind is None:
         dev = jax.devices()[0]
-        kind = "cpu" if dev.platform == "cpu" else dev.device_kind
+        if dev.platform == "cpu":
+            return 1.0
+        kind = dev.device_kind
     if kind not in DEVICE_BANDWIDTH:
         raise KeyError(f"no memory bandwidth recorded for device kind "
                        f"{kind!r}; add it to DEVICE_BANDWIDTH with its "
@@ -417,8 +419,8 @@ class MachineModel:
     """Bandwidth/latency model used to pre-seed decisions on a new machine.
 
     ``stream_bw`` defaults to the running device's entry in
-    :data:`DEVICE_BANDWIDTH`; the random-gather bandwidth is modelled as
-    ``gather_fraction`` of it.
+    :data:`DEVICE_BANDWIDTH` (1.0 on a host CPU); the random-gather
+    bandwidth is modelled as ``gather_fraction`` of it.
 
     ``segment_penalty`` models the segmented-reduction inefficiency of
     CSR/COO on vector hardware: the effective vector length is the row

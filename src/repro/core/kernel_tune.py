@@ -165,7 +165,7 @@ CSR_ROW_TILES = (128, 256, 512)
 CSR_NNZ_TILES = (512, 1024, 2048, 4096)
 #: cap on the largest per-step tile, in elements: the CSR kernel's
 #: (block_nnz, block_rows) one-hot row-recovery tile, and the ELL SpMM
-#: kernel's (block_w, block_k, block_rows) gathered-x panel.  It keeps
+#: kernel's (block_k, block_w, block_rows) gathered-x panel.  It keeps
 #: every candidate inside the compiler's default scoped VMEM.
 MAX_TILE = 1 << 20
 

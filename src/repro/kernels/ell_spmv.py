@@ -78,23 +78,24 @@ def ell_spmv(data_t: jax.Array, xg_t: jax.Array, *, block_rows: int = 256,
 
 
 def _ell_spmm_kernel(data_ref, xg_ref, y_ref):
-    """Multi-RHS: ``xg_ref`` is ``(block_w, block_k, block_rows)`` — the
-    gathered x rows of each band slot, right-hand sides on sublanes.
+    """Multi-RHS: ``xg_ref`` is ``(block_k, block_w, block_rows)`` — for
+    each right-hand side, the gathered x of each band slot, laid out like
+    VAL.  Each right-hand side is the SpMV body over that slab.
     Grid = (row_blocks, k_blocks, w_blocks); w innermost (sequential)."""
     j = pl.program_id(2)
     data = data_ref[...].astype(jnp.float32)              # (bw, br)
-    partial = jnp.zeros(y_ref.shape, jnp.float32)         # (bk, br)
-    for w in range(data.shape[0]):
-        partial = partial + data[w:w + 1, :] * \
-            xg_ref[w].astype(jnp.float32)
 
     @pl.when(j == 0)
     def _init():
-        y_ref[...] = partial
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
 
-    @pl.when(j != 0)
-    def _acc():
-        y_ref[...] = y_ref[...] + partial
+    def _rhs(c, carry):
+        row = jnp.sum(data * xg_ref[c].astype(jnp.float32), axis=0,
+                      keepdims=True)                      # (1, br)
+        y_ref[pl.ds(c, 1), :] = y_ref[pl.ds(c, 1), :] + row
+        return carry
+
+    jax.lax.fori_loop(0, y_ref.shape[0], _rhs, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_w",
@@ -103,10 +104,10 @@ def ell_spmm(data_t: jax.Array, xg_t: jax.Array, *, block_rows: int = 128,
              block_w: int = 8, block_k: int = 128,
              interpret: bool = False) -> jax.Array:
     """Y = A @ X with ``data_t`` ``(width, n_rows)`` and ``xg_t``
-    ``(width, k, n_rows)`` (``X[ICOL]`` with the RHS axis in the middle).
+    ``(k, width, n_rows)`` (``X[ICOL]`` with the RHS axis leading).
     Returns Y transposed, ``(k, n_rows)`` float32."""
     width, n_rows = data_t.shape
-    k = xg_t.shape[1]
+    k = xg_t.shape[0]
     assert n_rows % block_rows == 0 and width % block_w == 0 \
         and k % block_k == 0, (data_t.shape, xg_t.shape)
     return pl.pallas_call(
@@ -114,8 +115,8 @@ def ell_spmm(data_t: jax.Array, xg_t: jax.Array, *, block_rows: int = 128,
         grid=(n_rows // block_rows, k // block_k, width // block_w),
         in_specs=[
             pl.BlockSpec((block_w, block_rows), lambda i, kk, j: (j, i)),
-            pl.BlockSpec((block_w, block_k, block_rows),
-                         lambda i, kk, j: (j, kk, i)),
+            pl.BlockSpec((block_k, block_w, block_rows),
+                         lambda i, kk, j: (kk, j, i)),
         ],
         out_specs=pl.BlockSpec((block_k, block_rows),
                                lambda i, kk, j: (kk, i)),

@@ -95,12 +95,11 @@ def _block_k(k: int) -> int:
     return min(128, _align8(k))
 
 
-def _gather_rhs(x: jax.Array, idx: jax.Array, axis: int) -> jax.Array:
-    """``x[idx]`` for every right-hand side (column of ``x``), stacked on
-    ``axis``.  One 1-D gather per column keeps each gathered panel
-    lane-dense; a single 2-D gather would materialize an intermediate
-    with the right-hand sides minor, padded to 128 lanes."""
-    return jnp.stack([x[:, i][idx] for i in range(x.shape[1])], axis=axis)
+def _gather_rhs(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` for every right-hand side (column of ``x``) in one
+    gather from ``x.T``: ``(k, *idx.shape)``, lane-dense along ``idx``.
+    Indices are clamped like ``x[idx]`` (no out-of-range fill mask)."""
+    return jnp.take(x.T, idx, axis=1, mode="clip")
 
 
 # ---------------------------------------------------------------------------
@@ -135,7 +134,7 @@ def _ell_t_spmm(data_t: jax.Array, cols_t: jax.Array, x: jax.Array,
     bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
     data_t = _pad_to(_pad_to(data_t, 1, br), 0, bw)
     cols_t = _pad_to(_pad_to(cols_t, 1, br), 0, bw)
-    xg_t = _gather_rhs(_pad_to(x, 1, bk), cols_t, axis=1)   # (W, k, n)
+    xg_t = _gather_rhs(_pad_to(x, 1, bk), cols_t)   # (k, W, n)
     y_t = _ell.ell_spmm(data_t, xg_t, block_rows=br, block_w=bw,
                         block_k=bk, interpret=_interpret(interpret))
     return y_t[:k, :n_rows].T.astype(jnp.result_type(data_t.dtype, x.dtype))
@@ -263,7 +262,7 @@ def spmm_csr(m: CSR, x: jax.Array, interpret: Optional[bool] = None,
              tuning: Optional[TileGeometry] = None) -> jax.Array:
     k = x.shape[1]
     bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
-    xg_t = _gather_rhs(_pad_to(x, 1, bk), jnp.asarray(m.cols), axis=0)
+    xg_t = _gather_rhs(_pad_to(x, 1, bk), jnp.asarray(m.cols))
     y_t = _csr_launch(m, xg_t, bk, interpret, tuning)
     return y_t[:k, :m.n_rows].T.astype(
         jnp.result_type(m.data.dtype, x.dtype))

@@ -164,3 +164,13 @@ def test_memory_policy_blocks_ell_blowup():
     allowed = pol.allowed(("ell_row", "sell", "coo_row"), m)
     assert not allowed["ell_row"]   # the paper's torso1 ELL overflow
     assert allowed["coo_row"]
+
+
+def test_device_bandwidth_is_a_table_and_a_cpu_unit():
+    from repro.core.autotune import device_bandwidth
+    assert device_bandwidth("TPU v5 lite") == 819e9
+    with pytest.raises(KeyError):
+        device_bandwidth("TPU v9 imaginary")
+    if jax.devices()[0].platform == "cpu":
+        assert device_bandwidth() == 1.0
+        assert MachineModel().stream_bw == 1.0

@@ -4,7 +4,9 @@ Responsibilities:
   * gather ``x[ICOL]`` in XLA and lay VAL and the gathered panel out the
     way the kernels stream them (band-major for ELL, lane-dense rows for
     CSR), padded to legal TPU blocks (val=0/col=0 padding — the paper's
-    own ELL zero-fill convention, so padding never changes results);
+    own ELL zero-fill convention, so padding never changes results); the
+    gather runs under the ``gather_x`` named scope and the SELL row
+    scatter under ``reassemble``, so that a device trace names them;
   * accept the ``repro.core.formats`` pytree classes;
   * provide a custom VJP so the ELL kernel is trainable (y = A@x  =>
     dx = A^T dy via a scatter; dA = dy_r * x_c at the stored positions);
@@ -95,11 +97,16 @@ def _block_k(k: int) -> int:
     return min(128, _align8(k))
 
 
-def _gather_rhs(x: jax.Array, idx: jax.Array) -> jax.Array:
-    """``x[idx]`` for every right-hand side (column of ``x``) in one
-    gather from ``x.T``: ``(k, *idx.shape)``, lane-dense along ``idx``.
-    Indices are clamped like ``x[idx]`` (no out-of-range fill mask)."""
-    return jnp.take(x.T, idx, axis=1, mode="clip")
+def _gather_x(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[ICOL]``, under the ``gather_x`` scope so that a device trace
+    names it.  A vector gathers as ``x[idx]``; a panel ``(n_cols, k)``
+    gathers every right-hand side in one gather from ``x.T``:
+    ``(k, *idx.shape)``, lane-dense along ``idx``, indices clamped like
+    ``x[idx]`` (no out-of-range fill mask)."""
+    with jax.named_scope("gather_x"):
+        if x.ndim == 1:
+            return x[idx]
+        return jnp.take(x.T, idx, axis=1, mode="clip")
 
 
 # ---------------------------------------------------------------------------
@@ -120,8 +127,8 @@ def _ell_t_spmv(data_t: jax.Array, cols_t: jax.Array, x: jax.Array,
     br, bw = _ell_geometry(n_rows, width, tuning)
     data_t = _pad_to(_pad_to(data_t, 1, br), 0, bw)
     cols_t = _pad_to(_pad_to(cols_t, 1, br), 0, bw)
-    y = _ell.ell_spmv(data_t, x[cols_t], block_rows=br, block_w=bw,
-                      interpret=_interpret(interpret))
+    y = _ell.ell_spmv(data_t, _gather_x(x, cols_t), block_rows=br,
+                      block_w=bw, interpret=_interpret(interpret))
     return y[:n_rows].astype(jnp.result_type(data_t.dtype, x.dtype))
 
 
@@ -134,7 +141,7 @@ def _ell_t_spmm(data_t: jax.Array, cols_t: jax.Array, x: jax.Array,
     bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
     data_t = _pad_to(_pad_to(data_t, 1, br), 0, bw)
     cols_t = _pad_to(_pad_to(cols_t, 1, br), 0, bw)
-    xg_t = _gather_rhs(_pad_to(x, 1, bk), cols_t)   # (k, W, n)
+    xg_t = _gather_x(_pad_to(x, 1, bk), cols_t)     # (k, W, n)
     y_t = _ell.ell_spmm(data_t, xg_t, block_rows=br, block_w=bw,
                         block_k=bk, interpret=_interpret(interpret))
     return y_t[:k, :n_rows].T.astype(jnp.result_type(data_t.dtype, x.dtype))
@@ -253,7 +260,7 @@ def _csr_launch(m: CSR, xg_t: jax.Array, bk: int,
 def spmv_csr(m: CSR, x: jax.Array, interpret: Optional[bool] = None,
              tuning: Optional[TileGeometry] = None) -> jax.Array:
     """CSR through the native row-segmented kernel (SpMV is its k=1 case)."""
-    xg_t = x[jnp.asarray(m.cols)][None, :]
+    xg_t = _gather_x(x, jnp.asarray(m.cols))[None, :]
     y_t = _csr_launch(m, xg_t, 1, interpret, tuning)
     return y_t[0, :m.n_rows].astype(jnp.result_type(m.data.dtype, x.dtype))
 
@@ -262,7 +269,7 @@ def spmm_csr(m: CSR, x: jax.Array, interpret: Optional[bool] = None,
              tuning: Optional[TileGeometry] = None) -> jax.Array:
     k = x.shape[1]
     bk = _geom(tuning, "block_k", _block_k(k), cap=_align8(k))
-    xg_t = _gather_rhs(_pad_to(x, 1, bk), jnp.asarray(m.cols))
+    xg_t = _gather_x(_pad_to(x, 1, bk), jnp.asarray(m.cols))
     y_t = _csr_launch(m, xg_t, bk, interpret, tuning)
     return y_t[:k, :m.n_rows].T.astype(
         jnp.result_type(m.data.dtype, x.dtype))
@@ -310,7 +317,8 @@ def spmv_sell(m: BucketedELL, x: jax.Array,
     y = jnp.zeros((m.n_rows,), x.dtype)
     for off, b, g in zip(m.row_offsets, m.buckets, _sell_tunings(m, tuning)):
         yb = spmv_ell(b, x, interpret, g)
-        y = y.at[perm[off:off + b.n_rows]].set(yb.astype(y.dtype))
+        with jax.named_scope("reassemble"):
+            y = y.at[perm[off:off + b.n_rows]].set(yb.astype(y.dtype))
     return y
 
 
@@ -321,7 +329,8 @@ def spmm_sell(m: BucketedELL, x: jax.Array,
     y = jnp.zeros((m.n_rows, x.shape[1]), x.dtype)
     for off, b, g in zip(m.row_offsets, m.buckets, _sell_tunings(m, tuning)):
         yb = spmm_ell(b, x, interpret, g)
-        y = y.at[perm[off:off + b.n_rows]].set(yb.astype(y.dtype))
+        with jax.named_scope("reassemble"):
+            y = y.at[perm[off:off + b.n_rows]].set(yb.astype(y.dtype))
     return y
 
 

@@ -14,7 +14,13 @@ reports into:
   conversion;
 * ``dispatch`` — kernel-tier vs reference-tier resolution counters;
 * ``SpMVService`` — per-key query-latency histograms, queue-depth
-  gauges, flush-cause counters, plan-replay hit/miss.
+  gauges, flush-cause counters, plan-replay hit/miss; spans for each
+  call, enqueue, flush, panel, scatter, guarded dispatch and finite
+  probe; queue-wait histograms and the host bytes each call copies.
+
+While telemetry is on, every span is also a ``jax.profiler``
+annotation (when JAX is loaded), so a profiler trace shows the spans on
+the device operations' clock.
 
 Telemetry is **off by default** — the hot path pays one flag check.
 Enable programmatically::

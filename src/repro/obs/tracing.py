@@ -11,13 +11,18 @@ array of complete ``"ph": "X"`` events, microsecond timestamps), which
 both ``chrome://tracing`` and Perfetto load directly; see
 :func:`chrome_trace`.
 
-This module is dependency-free (stdlib only) and knows nothing about the
+While a span is open it is also a ``jax.profiler.TraceAnnotation`` of
+the same name and attributes, so a profiler trace shows the program's
+spans on its ``/host:CPU`` plane, on the device operations' clock.  The
+annotation class is taken only when ``jax`` is already imported: this
+module stays dependency-free (stdlib only) and knows nothing about the
 rest of the library — :mod:`repro.obs.telemetry` owns the clock and the
 span stack and calls into it.
 """
 from __future__ import annotations
 
 import os
+import sys
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional
@@ -98,19 +103,34 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _open_annotation(name: str, attrs: Dict[str, Any]) -> Any:
+    """A profiler annotation entered for a span, or ``None`` while JAX is
+    not loaded (importing it here would make every span pay for it)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(name, **attrs)
+    ann.__enter__()
+    return ann
+
+
 class SpanContext:
     """The live context manager behind ``Telemetry.span`` (enabled path).
 
     Entering opens a :class:`Span` parented to the thread's innermost
-    open span; exiting stamps the end time and hands the finished span to
-    the telemetry registry (bounded buffer + sinks)."""
-    __slots__ = ("_tel", "_name", "_attrs", "span")
+    open span, and its profiler annotation; exiting closes the annotation
+    on the same thread (with any attribute set inside the block), stamps
+    the end time and hands the finished span to the telemetry registry
+    (bounded buffer + sinks)."""
+    __slots__ = ("_tel", "_name", "_attrs", "span", "_ann", "_n_attrs")
 
     def __init__(self, tel: Any, name: str, attrs: Dict[str, Any]):
         self._tel = tel
         self._name = name
         self._attrs = attrs
         self.span: Optional[Span] = None
+        self._ann: Any = None
+        self._n_attrs = 0
 
     def __enter__(self) -> Span:
         tel = self._tel
@@ -121,10 +141,18 @@ class SpanContext:
                   tid=threading.get_ident())
         stack.append(sp)
         self.span = sp
+        self._n_attrs = len(self._attrs)
+        self._ann = _open_annotation(self._name, self._attrs)
         return sp
 
     def __exit__(self, *exc: Any) -> bool:
         sp = self.span
+        ann = self._ann
+        if ann is not None:
+            if len(sp.attrs) > self._n_attrs:   # set inside the block
+                ann.set_metadata(
+                    **dict(list(sp.attrs.items())[self._n_attrs:]))
+            ann.__exit__(None, None, None)
         sp.t_end = self._tel.clock()
         stack = self._tel._span_stack()
         if stack and stack[-1] is sp:

@@ -336,7 +336,8 @@ def spmv_hybrid(m: HybridMatrix, x: jax.Array,
     y = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
     if m.identity_perm:
         return y
-    return jnp.zeros(m.n_rows, y.dtype).at[jnp.asarray(m.perm)].set(y)
+    with jax.named_scope("reassemble"):
+        return jnp.zeros(m.n_rows, y.dtype).at[jnp.asarray(m.perm)].set(y)
 
 
 def spmm_hybrid(m: HybridMatrix, x: jax.Array,
@@ -348,8 +349,9 @@ def spmm_hybrid(m: HybridMatrix, x: jax.Array,
     y = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
     if m.identity_perm:
         return y
-    return jnp.zeros((m.n_rows, x.shape[1]),
-                     y.dtype).at[jnp.asarray(m.perm)].set(y)
+    with jax.named_scope("reassemble"):
+        return jnp.zeros((m.n_rows, x.shape[1]),
+                         y.dtype).at[jnp.asarray(m.perm)].set(y)
 
 
 # the hybrid container is a first-class format: one registration here is

@@ -24,7 +24,9 @@ makes that fallback a first-class serving construct:
 
 Failure detection, fallbacks, and breaker transitions are exported through
 :mod:`repro.obs` (``service.fallback`` / ``guard.failure`` counters,
-``guard.breaker`` events) and surface in ``SpMVService.stats()``.
+``guard.breaker`` events) and surface in ``SpMVService.stats()``; with
+telemetry on, each rung's call is a ``guard.dispatch`` span and each
+finite probe a ``guard.probe`` span.
 
 Fault injection (:mod:`repro.serve.faults`) is threaded through the tuned
 rung only — ``kernel.raise`` raises before it runs, ``kernel.nan``
@@ -120,11 +122,8 @@ class CircuitBreaker:
             tel.event("guard.breaker", key=self.key, fmt=self.fmt,
                       op=self.op, frm=frm, to=to,
                       consecutive=self.consecutive)
-            tel.gauge("guard.breaker_open", key=self.key, fmt=self.fmt,
-                      op=self.op).set(1.0 if to == OPEN else 0.0)
-            # full state machine as a labelled gauge (0=closed, 1=open,
-            # 2=half_open) so dashboards see half-open probes, not just
-            # the open/closed projection above
+            # the state machine as a labelled gauge (0=closed, 1=open,
+            # 2=half_open) so dashboards see half-open probes too
             tel.gauge("service.breaker_state", key=self.key, fmt=self.fmt,
                       op=self.op).set(float(STATE_CODES[to]))
 
@@ -179,6 +178,11 @@ class GuardedImpl:
         import jax.numpy as jnp
         return bool(jax.device_get(jnp.all(jnp.isfinite(y))))
 
+    def _probe(self, y: Any, tel) -> bool:
+        with (tel.span("guard.probe", key=self.key, op=self.op)
+              if tel.enabled else _obs.NOOP_SPAN):
+            return self._finite(y)
+
     def _fail(self, rung: Rung, reason: str, tel) -> None:
         k = f"{rung.name}/{reason}"
         self.failures[k] = self.failures.get(k, 0) + 1
@@ -210,7 +214,10 @@ class GuardedImpl:
                 if rung.inject:
                     reg.maybe_raise("kernel.raise")
                 t0 = self.clock()
-                y = rung.fn(x)
+                with (tel.span("guard.dispatch", key=self.key, op=self.op,
+                               rung=rung.name)
+                      if tel.enabled else _obs.NOOP_SPAN):
+                    y = rung.fn(x)      # argument transfer and launch
                 if rung.inject and reg.should_fire("kernel.nan"):
                     import jax.numpy as jnp
                     y = jnp.full_like(y, jnp.nan)
@@ -225,7 +232,7 @@ class GuardedImpl:
                                 f"rung {rung.name!r} blew its "
                                 f"{self.budget_s}s budget")))
                             continue
-                    if self.probe_finite and not self._finite(y):
+                    if self.probe_finite and not self._probe(y, tel):
                         self._fail(rung, "non_finite", tel)
                         causes.append((rung.name, FloatingPointError(
                             f"non-finite output from rung {rung.name!r}")))

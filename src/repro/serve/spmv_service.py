@@ -104,6 +104,14 @@ def _swallow(where: str, err: BaseException) -> None:
         tel.event("service.swallowed_error", where=where, error=repr(err))
 
 
+def host_nbytes(tree: Any) -> int:
+    """Bytes of the host arrays (``np.ndarray`` leaves, not ``jax.Array``)
+    in ``tree``: what every jitted call that takes ``tree`` as an argument
+    copies from host memory to the device."""
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)
+               if isinstance(leaf, np.ndarray))
+
+
 def _cache_size(fn: Optional[Callable]) -> int:
     """Compiled-executable count of a jitted dispatcher (0 if unavailable)."""
     try:
@@ -153,6 +161,12 @@ class MatrixEntry:
     deltas: int = 0             # DeltaBatches absorbed by this key
     replans: int = 0            # drift-triggered re-registrations
     last_stream_decision: Optional[Any] = None  # stream.drift.DriftDecision
+    # host bytes of ``matrix`` that each served call copies to the device
+    # (the ``service.host_bytes`` counter); kept in step with ``matrix``
+    host_bytes: int = field(default=0, init=False)
+
+    def __post_init__(self) -> None:
+        self.host_bytes = host_nbytes(self.matrix)
 
     def formats(self) -> Dict[str, int]:
         return self.report.format_counts()
@@ -729,8 +743,10 @@ class SpMVService:
                 perm=perm, blocks=(res.container,), row_offsets=(0,),
                 formats=(fmt,), shape=res.csr.shape, nnz=res.csr.nnz,
                 identity_perm=True)
+            host_bytes = host_nbytes(new_hyb)
             with entry.lock:
                 entry.matrix = new_hyb
+                entry.host_bytes = host_bytes
                 entry.source = res.csr
                 entry.deltas += 1
             entry.sketch.update(res)
@@ -794,17 +810,36 @@ class SpMVService:
         fn = entry.fn if op == "spmv" else entry.spmm_fn
         return jax.block_until_ready(fn(entry.matrix, x))
 
+    def _serve(self, entry: MatrixEntry, key: str, op: str, x: jax.Array,
+               span: Any = _obs.NOOP_SPAN) -> jax.Array:
+        """:meth:`_run`, and with telemetry on: count the host bytes the
+        call copies, and mark ``span`` ``compiled`` when the call compiled
+        a dispatcher."""
+        tel = _obs.get()
+        if not tel.enabled:
+            return self._run(entry, op, x)
+        tel.counter("service.host_bytes", key=key,
+                    op=op).inc(entry.host_bytes)
+        jitted = hasattr(entry.fn, "_cache_size")   # sharded: plain calls
+        compiles = entry.compile_count() if jitted else 0
+        y = self._run(entry, op, x)
+        if jitted and entry.compile_count() > compiles:
+            span.set(compiled=True)
+        return y
+
     def spmv(self, key: str, x: jax.Array) -> jax.Array:
         entry = self.entries[key]
-        t0 = self._now()
-        y = self._run(entry, "spmv", jnp.asarray(x))
-        dt = self._now() - t0
+        tel = _obs.get()
+        with (tel.span("service.spmv", key=key) if tel.enabled
+              else _obs.NOOP_SPAN) as sp:
+            t0 = self._now()
+            y = self._serve(entry, key, "spmv", jnp.asarray(x), sp)
+            dt = self._now() - t0
         with entry.lock:
             entry.n_calls += 1
             entry.t_serve += dt
             if entry.stream_policy is not None:
                 entry.stream_policy.note_query()
-        tel = _obs.get()
         if tel.enabled:
             tel.histogram("service.query_latency_s", key=key,
                           op="spmv").observe(dt)
@@ -817,7 +852,7 @@ class SpMVService:
         if x.ndim != 2:
             raise ValueError(f"spmm expects (n_cols, B); got {x.shape}")
         t0 = self._now()
-        y = self._run(entry, "spmm", x)
+        y = self._serve(entry, key, "spmm", x)
         dt = self._now() - t0
         with entry.lock:
             entry.n_spmm_calls += 1
@@ -879,6 +914,12 @@ class SpMVService:
         ``admission`` policy: ``reject`` raises :class:`AdmissionError`,
         ``shed_oldest`` fails the oldest pending future to make room,
         ``block`` flushes synchronously until there is room."""
+        tel = _obs.get()
+        with (tel.span("service.submit", key=key) if tel.enabled
+              else _obs.NOOP_SPAN):
+            return self._submit(key, x)
+
+    def _submit(self, key: str, x: jax.Array) -> "Future":
         entry = self.entries[key]
         x = jnp.asarray(x)
         if x.shape != (entry.matrix.n_cols,):
@@ -972,38 +1013,49 @@ class SpMVService:
             return 0
         b = len(batch)
         tel = _obs.get()
-        with tel.span("service.flush", key=key, cause=cause, batch=b):
+        if tel.enabled:
+            t_flush = self._now()
+            waits = tel.histogram("service.queue_wait_s", key=key)
+            for _, _, t_enq in batch:
+                waits.observe(t_flush - t_enq)
+        with (tel.span("service.flush", key=key, cause=cause, batch=b)
+              if tel.enabled else _obs.NOOP_SPAN) as sp:
             try:
-                X = jnp.stack([x for _, x, _ in batch], axis=1)  # (n_cols, b)
-                panel = entry.max_batch or self.max_batch
-                if self.pad_batches and b < panel:
-                    X = jnp.pad(X, ((0, 0), (0, panel - b)))
+                with (tel.span("service.panel", key=key, batch=b)
+                      if tel.enabled else _obs.NOOP_SPAN):
+                    X = jnp.stack([x for _, x, _ in batch], axis=1)
+                    panel = entry.max_batch or self.max_batch
+                    if self.pad_batches and b < panel:    # (n_cols, panel)
+                        X = jnp.pad(X, ((0, 0), (0, panel - b)))
                 t0 = self._now()
-                Y = self._run(entry, "spmm", X)
+                Y = self._serve(entry, key, "spmm", X, sp)
             except Exception as e:
                 # never strand a future: the whole panel fails together
                 for fut, _, _ in batch:
                     fut.set_exception(e)
                 raise
             dt = self._now() - t0
-        if tel.enabled:
-            tel.counter("service.flush", key=key, cause=cause).inc()
-            tel.gauge("service.queue_depth", key=key).set(0)
-            tel.histogram("service.flush_latency_s", key=key).observe(dt)
-            tel.event("service.flush", key=key, cause=cause, batch=b,
-                      t_spmm=dt)
-        with entry.lock:
-            entry.n_spmm_calls += 1
-            entry.n_spmm_cols += b
-            entry.t_serve += dt
-            if entry.stream_policy is not None:
-                entry.stream_policy.note_query(b)
-            # the admission controller's wait predictor: a slow-moving EMA
-            # of flush latency (zero-cost under FakeClock — dt stays 0)
-            entry.flush_ema_s = (dt if entry.flush_ema_s == 0.0
-                                 else 0.3 * dt + 0.7 * entry.flush_ema_s)
-        for i, (fut, _, _) in enumerate(batch):
-            fut.set_result(Y[:, i])
+            if tel.enabled:
+                tel.counter("service.flush", key=key, cause=cause).inc()
+                tel.gauge("service.queue_depth", key=key).set(0)
+                tel.histogram("service.flush_latency_s", key=key).observe(dt)
+                tel.event("service.flush", key=key, cause=cause, batch=b,
+                          t_spmm=dt)
+            with entry.lock:
+                entry.n_spmm_calls += 1
+                entry.n_spmm_cols += b
+                entry.t_serve += dt
+                if entry.stream_policy is not None:
+                    entry.stream_policy.note_query(b)
+                # the admission controller's wait predictor: a slow-moving
+                # EMA of flush latency (zero-cost under FakeClock — dt
+                # stays 0)
+                entry.flush_ema_s = (dt if entry.flush_ema_s == 0.0
+                                     else 0.3 * dt + 0.7 * entry.flush_ema_s)
+            with (tel.span("service.scatter", key=key, batch=b)
+                  if tel.enabled else _obs.NOOP_SPAN):
+                for i, (fut, _, _) in enumerate(batch):
+                    fut.set_result(Y[:, i])
         return b
 
     # -- lifecycle -----------------------------------------------------------

@@ -488,7 +488,7 @@ def memory_bytes(fmt) -> int:
     """Storage footprint of a format instance (index + value arrays)."""
     total = 0
     for leaf in jax.tree_util.tree_leaves(fmt):
-        total += int(np.prod(leaf.shape)) * _np(leaf).dtype.itemsize
+        total += int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
     return total
 
 

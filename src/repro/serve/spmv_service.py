@@ -107,7 +107,8 @@ def _swallow(where: str, err: BaseException) -> None:
 def host_nbytes(tree: Any) -> int:
     """Bytes of the host arrays (``np.ndarray`` leaves, not ``jax.Array``)
     in ``tree``: what every jitted call that takes ``tree`` as an argument
-    copies from host memory to the device."""
+    copies from host memory to the device (0 once :meth:`SpMVService._place`
+    has put it there)."""
     return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)
                if isinstance(leaf, np.ndarray))
 
@@ -143,6 +144,9 @@ class MatrixEntry:
     max_batch: Optional[int] = None  # per-key panel width (plan-seeded);
     #                                  None falls through to the service's
     source: Optional[CSR] = None     # kept for the reference-CSR rung
+    # host form of ``matrix`` (streaming keys only): the container the next
+    # incremental apply rewrites before it is placed on the device again
+    host_matrix: Optional[Any] = None
     guards: Dict[str, GuardedImpl] = field(default_factory=dict)
     flush_ema_s: float = 0.0    # EMA of flush latency, drives admission
     shed: int = 0               # requests dropped by shed_oldest
@@ -162,7 +166,8 @@ class MatrixEntry:
     replans: int = 0            # drift-triggered re-registrations
     last_stream_decision: Optional[Any] = None  # stream.drift.DriftDecision
     # host bytes of ``matrix`` that each served call copies to the device
-    # (the ``service.host_bytes`` counter); kept in step with ``matrix``
+    # (the ``service.host_bytes`` counter; 0 for a device-resident
+    # operator); kept in step with ``matrix``
     host_bytes: int = field(default=0, init=False)
 
     def __post_init__(self) -> None:
@@ -333,6 +338,17 @@ class SpMVService:
             clock=self._now) for op in ("spmv", "spmm")}
 
     # -- registration --------------------------------------------------------
+    def _place(self, hyb: Any) -> Any:
+        """``hyb`` with every array leaf on the device, blocked until ready
+        (span ``service.place``, attribute ``bytes``).  The operator is
+        placed once, at registration and at each streaming swap, so no
+        served call copies it from host memory."""
+        with _obs.get().span("service.place") as sp:
+            placed = jax.block_until_ready(jax.device_put(hyb))
+            sp.set(bytes=sum(leaf.nbytes for leaf in
+                             jax.tree_util.tree_leaves(placed)))
+        return placed
+
     def _lint_registered_plan(self, key: str, plan: Any,
                               strict: bool) -> Any:
         """Static lint of a caller-supplied plan before it is bound.
@@ -476,6 +492,7 @@ class SpMVService:
                 plan_matched = self._build_operator(
                     key, csr, plan, plan_matched, expected_iterations,
                     batch, build_kw, tel)
+            matrix = self._place(hyb)
             fn = jax.jit(lambda m, x: spmv_hybrid(m, x, impls=impls))
             spmm_fn = jax.jit(
                 lambda m, x: spmm_hybrid(m, x, impls=spmm_impls))
@@ -486,12 +503,13 @@ class SpMVService:
             x0 = jnp.ones((csr.n_cols,), jnp.float32)
             t_csr = time_fn(jax.jit(spmv_ref), csr, x0, iters=1,
                             warmup=1)
-            t_hyb = time_fn(fn, hyb, x0, iters=1, warmup=1)
-        entry = MatrixEntry(matrix=hyb, report=report, fn=fn,
+            t_hyb = time_fn(fn, matrix, x0, iters=1, warmup=1)
+        entry = MatrixEntry(matrix=matrix, report=report, fn=fn,
                             spmm_fn=spmm_fn, t_build=t_build, t_csr=t_csr,
                             t_hybrid=t_hyb, builds=builds, tunings=tunings,
                             plan=entry_plan, from_plan=plan_matched,
                             source=csr,
+                            host_matrix=hyb if streaming else None,
                             max_batch=(plan.batch if plan is not None
                                        and plan.batch > 1 else None))
         entry.guards = self._build_guards(key, entry, fmt="hybrid")
@@ -701,8 +719,9 @@ class SpMVService:
         so queued futures are served against the matrix they were
         submitted for — deltas serialize with the flush queue.  A
         single-block CSR/SELL operator is updated *incrementally*
-        (O(Δnnz) tail appends, per-slice SELL rebuilds) by swapping the
-        entry's containers in place — the compiled dispatchers and guard
+        (O(Δnnz) tail appends, per-slice SELL rebuilds) on its host form
+        (``entry.host_matrix``), which is placed on the device again and
+        swapped into the entry — the compiled dispatchers and guard
         ladders read the entry dynamically, so no rebind happens and the
         per-``(key, fmt, op)`` circuit breakers keep their state.  Any
         other operator shape degrades to a CSR apply plus a full
@@ -724,7 +743,7 @@ class SpMVService:
             # the panel's futures already carry the exception; the delta
             # must still land or the key's state forks from its writers
             _swallow("delta_flush", e)
-        hyb = entry.matrix
+        hyb = entry.host_matrix
         leaf = (getattr(hyb, "n_blocks", 0) == 1
                 and getattr(hyb, "identity_perm", False)
                 and hyb.formats[0] in INCREMENTAL_FORMATS)
@@ -743,10 +762,11 @@ class SpMVService:
                 perm=perm, blocks=(res.container,), row_offsets=(0,),
                 formats=(fmt,), shape=res.csr.shape, nnz=res.csr.nnz,
                 identity_perm=True)
-            host_bytes = host_nbytes(new_hyb)
+            placed = self._place(new_hyb)
             with entry.lock:
-                entry.matrix = new_hyb
-                entry.host_bytes = host_bytes
+                entry.matrix = placed
+                entry.host_matrix = new_hyb
+                entry.host_bytes = host_nbytes(placed)
                 entry.source = res.csr
                 entry.deltas += 1
             entry.sketch.update(res)
@@ -922,6 +942,8 @@ class SpMVService:
     def _submit(self, key: str, x: jax.Array) -> "Future":
         entry = self.entries[key]
         x = jnp.asarray(x)
+        if entry.matrix is None:    # released by a racing evict/re-register
+            raise EvictedError(f"matrix {key!r} was evicted")
         if x.shape != (entry.matrix.n_cols,):
             # reject here so one bad vector can never poison a whole panel
             raise ValueError(f"expected x of shape ({entry.matrix.n_cols},); "
@@ -1085,7 +1107,9 @@ class SpMVService:
         # if a caller keeps the MatrixEntry alive
         entry.fn = entry.spmm_fn = _evicted
         entry.guards = {}
-        entry.source = None
+        # drop the device copy with the entry: a re-registered key keeps
+        # one operator on the chip past the swap
+        entry.matrix = entry.host_matrix = entry.source = None
 
     def _entry_telemetry(self, key: str) -> Dict[str, Any]:
         """This key's slice of the process telemetry (query-latency
@@ -1118,11 +1142,12 @@ class SpMVService:
             saved = (products * (e.t_csr - e.t_hybrid)
                      if e.t_csr > 0 else None)
             nb = getattr(e.matrix, "nbytes", None)
+            nbytes = int(nb()) if callable(nb) else memory_bytes(e.matrix)
             out[key] = {
                 "n_blocks": e.matrix.n_blocks,
                 "formats": e.formats(),
-                "bytes": int(nb()) if callable(nb) else memory_bytes(
-                    e.matrix),
+                "bytes": nbytes,
+                "device_bytes": nbytes - e.host_bytes,
                 "t_build_s": e.t_build,
                 "n_calls": e.n_calls,
                 "n_spmm_calls": e.n_spmm_calls,

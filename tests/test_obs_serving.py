@@ -194,23 +194,28 @@ def test_queue_wait_runs_from_enqueue_to_flush_start(tel):
     assert h.sum == pytest.approx(0.005 + 0.003 + 0.0 + 0.001)
 
 
+def _on_device(matrix):
+    leaves = jax.tree_util.tree_leaves(matrix)
+    return bool(leaves) and all(isinstance(a, jax.Array) for a in leaves)
+
+
 def test_host_bytes_counted_once_per_spmv_and_per_flush(tel):
     csr = _csr()
     svc = SpMVService(max_batch=2)
     entry = svc.register("m", csr, measure_baseline=False)
-    leaves = [a for a in jax.tree_util.tree_leaves(entry.matrix)
-              if isinstance(a, np.ndarray)]
-    assert leaves, "the served operator holds host arrays"
-    assert entry.host_bytes == sum(a.nbytes for a in leaves) > 0
+    assert _on_device(entry.matrix), "the served operator stays on the chip"
+    assert entry.host_bytes == host_nbytes(entry.matrix) == 0
+    assert entry.host_matrix is None            # no second copy
     x = np.ones(csr.n_cols, np.float32)
     svc.spmv("m", x)
     svc.spmv("m", x)
     svc.submit("m", x)
     svc.submit("m", x)                          # one flush
     counters = tel.snapshot()["counters"]
-    assert counters["service.host_bytes{key=m,op=spmv}"] == \
-        2 * entry.host_bytes
-    assert counters["service.host_bytes{key=m,op=spmm}"] == entry.host_bytes
+    assert counters["service.host_bytes{key=m,op=spmv}"] == 0
+    assert counters["service.host_bytes{key=m,op=spmm}"] == 0
+    assert len(_spans(tel, "service.spmv")) == 2
+    assert len(_spans(tel, "service.flush")) == 1
 
 
 def test_host_bytes_ignores_device_arrays():
@@ -224,15 +229,69 @@ def test_host_bytes_recomputed_after_streaming_swap(tel):
     svc = SpMVService()
     svc.register("m", csr, measure_baseline=False,
                  plan=Planner().plan(csr, fmt="sell"), streaming=True)
-    before = svc.entries["m"].host_bytes
-    res = svc.apply_delta("m", random_delta(rng, csr, n_appends=4,
-                                            n_updates=2))
-    assert not res.fallback                     # swapped in place
-    entry = svc.entries["m"]
-    assert entry.host_bytes == host_nbytes(entry.matrix) != before
-    svc.spmv("m", np.ones(csr.n_cols, np.float32))
+    x = rng.normal(size=csr.n_cols).astype(np.float32)
+    for n_appends in (4, 3):                    # the second delta applies
+        old = svc.entries["m"].matrix           # to the first one's result
+        res = svc.apply_delta("m", random_delta(rng, csr, n_appends=n_appends,
+                                                n_updates=2))
+        assert not res.fallback and res.mode != "rebuild"   # incremental
+        entry = svc.entries["m"]
+        assert entry.matrix is not old and _on_device(entry.matrix)
+        assert not _on_device(entry.host_matrix)    # the host form, kept
+        assert entry.host_bytes == host_nbytes(entry.matrix) == 0
+        np.testing.assert_allclose(np.asarray(svc.spmv("m", x)),
+                                   entry.source.todense() @ x,
+                                   rtol=1e-5, atol=1e-5)
+    assert svc.entries["m"].deltas == 2
     assert tel.snapshot()["counters"][
-        "service.host_bytes{key=m,op=spmv}"] == entry.host_bytes
+        "service.host_bytes{key=m,op=spmv}"] == 0
+
+
+def test_plan_replay_places_the_operator(tel):
+    csr = _csr()
+    svc = SpMVService()
+    plan = svc.register("a", csr, measure_baseline=False).plan
+    entry = svc.register("b", csr, measure_baseline=False, plan=plan)
+    assert entry.from_plan and _on_device(entry.matrix)
+    assert entry.host_bytes == 0
+    x = np.ones(csr.n_cols, np.float32)
+    np.testing.assert_allclose(np.asarray(svc.spmv("b", x)),
+                               csr.todense() @ x, rtol=1e-5, atol=1e-5)
+
+
+def test_degraded_registration_places_the_operator(tel):
+    csr = _csr()
+    svc = SpMVService()
+    with faults.inject("transform.raise", prob=1.0):
+        entry = svc.register("m", csr, measure_baseline=False)
+    assert entry.plan.rule == "degraded" and entry.matrix.formats == ("csr",)
+    assert _on_device(entry.matrix) and entry.host_bytes == 0
+    assert entry.source is not None and host_nbytes(entry.source) > 0
+    x = np.ones(csr.n_cols, np.float32)
+    np.testing.assert_allclose(np.asarray(svc.spmv("m", x)),
+                               csr.todense() @ x, rtol=1e-5, atol=1e-5)
+
+
+def test_place_span_records_the_placed_bytes(tel):
+    csr = _csr()
+    svc = SpMVService()
+    entry = svc.register("m", csr, measure_baseline=False)
+    (reg,) = _spans(tel, "service.register")
+    (place,) = _spans(tel, "service.place")
+    assert place["parent_id"] == reg["span_id"]
+    leaf_bytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(entry.matrix))
+    assert place["attrs"]["bytes"] == leaf_bytes > 0
+    assert svc.stats()["m"]["device_bytes"] == leaf_bytes
+
+
+def test_evict_and_reregister_drop_the_device_copy(tel):
+    csr = _csr()
+    svc = SpMVService()
+    first = svc.register("m", csr, measure_baseline=False)
+    second = svc.register("m", csr, measure_baseline=False)
+    assert first.matrix is None and _on_device(second.matrix)
+    svc.evict("m")
+    assert second.matrix is None
 
 
 def test_breaker_reports_its_state_gauge_only(tel):

@@ -13,10 +13,14 @@ rows is one lane-dense vector row, and the reduction over the band is a
 sublane reduction.  The output is a lane-dense ``(1, n_rows)`` row
 (SpMM: ``(k, n_rows)``), never a 1-D or lane-width-1 block.
 
-The x gather happens in XLA before the kernel: ``x[ICOL]`` is streamed in
-as a dense panel of the same shape as VAL, so the kernel body is a dense
-multiply-reduce (Mosaic has no general 1-D gather).  x is therefore never
-pinned in VMEM, and its size is bounded by HBM only.
+The x gather happens in XLA before the kernel (``ops.py``): ``x[ICOL]`` is
+streamed in as a dense panel of the same shape as VAL, so the kernel body
+is a dense multiply-reduce (Mosaic has no general 1-D gather).  x is
+therefore never pinned in VMEM, and its size is bounded by HBM only.  For
+SpMV, a block whose every run of 8 band slots of a row reads at most 8
+consecutive columns gathers one 8-wide slice of x per run and selects
+each slot's value from it; any other block, and SpMM, gathers one element
+per slot.  Either way the kernel reads the same panel.
 
 Block alignment: ``block_w % 8 == 0`` (sublanes); ``block_rows`` is a
 multiple of 128 (lanes) or covers every row.  The ops.py wrapper pads

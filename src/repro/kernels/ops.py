@@ -7,6 +7,14 @@ Responsibilities:
     own ELL zero-fill convention, so padding never changes results); the
     gather runs under the ``gather_x`` named scope and the SELL row
     scatter under ``reassemble``, so that a device trace names them;
+  * gather x for an ELL block's SpMV by column runs where the block
+    allows it: when every run of 8 band slots of a row (the band tile's 8
+    sublanes) reads at most 8 consecutive columns, one 8-wide column of a
+    window panel ``xw[j, c] = x[c + j]`` per run, and a select puts each
+    slot's value in place (``_gather_band``; an eighth of the indices,
+    and a padding slot reads 0.0).  The block's own columns decide, on
+    the device; any other block, the SpMM path and CSR gather one element
+    per index, as ``_gather_x`` always did;
   * accept the ``repro.core.formats`` pytree classes;
   * provide a custom VJP so the ELL kernel is trainable (y = A@x  =>
     dx = A^T dy via a scatter; dA = dy_r * x_c at the stored positions);
@@ -110,6 +118,96 @@ def _gather_x(x: jax.Array, idx: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# x gathered by column runs (the SpMV path of ELL blocks)
+# ---------------------------------------------------------------------------
+#: band slots per run: the ELL band tile's 8 sublanes (``block_w % 8 == 0``)
+RUN = 8
+
+
+def band_runs(data_t, cols_t, xp=jnp):
+    """Cut a band-major ELL block ``(W, n_rows)`` into runs of ``RUN``
+    consecutive band slots of one row: ``(cols, zero, base, fits)``.
+
+    ``cols`` is the block's columns as ``(W/RUN, RUN, n_rows)``, the band
+    padded to a multiple of ``RUN`` slots; ``zero`` marks the padding
+    slots, those whose value and column are both 0 (the ELL zero-fill);
+    ``base`` ``(W/RUN, n_rows)`` is the least column among a run's real
+    slots (0 for a run of padding); ``fits`` is whether every real slot
+    lies within ``RUN`` columns of its run's base, so that one ``RUN``-wide
+    slice of x per run holds every value the run reads (a padding slot's
+    column 0 never raises a run's greatest column).  ``xp`` is ``numpy``
+    for host arrays, ``jax.numpy`` under trace."""
+    pad = (0, -cols_t.shape[0] % RUN), (0, 0)
+    data_t, cols_t = xp.pad(data_t, pad), xp.pad(cols_t, pad)
+    cols = cols_t.reshape(-1, RUN, cols_t.shape[1])
+    zero = (data_t.reshape(cols.shape) == 0) & (cols == 0)
+    top = np.iinfo(cols.dtype).max
+    base = xp.where(zero, top, cols).min(axis=1)
+    fits = (cols.max(axis=1) - base < RUN).all()
+    return cols, zero, xp.where(base == top, 0, base), fits
+
+
+def _gather_runs(xw: jax.Array, cols: jax.Array, zero: jax.Array,
+                 base: jax.Array) -> jax.Array:
+    """``x[ICOL]`` of a block whose runs fit (:func:`band_runs`): one
+    column of the window panel ``xw[j, c] = x[c + j]`` per run, then each
+    slot picks its column's value out of its run's ``RUN`` values by an
+    ``RUN``-way select.  Values are exact copies of x, and a padding slot
+    reads 0.0, not ``x[0]``.  Returns the ``(W, n_rows)`` panel, laid out
+    like VAL."""
+    g = jnp.take(xw, base, axis=1, mode="clip")   # (RUN, W/RUN, n_rows)
+    off = jnp.where(zero, -1, cols - base[:, None, :])
+    xg = jnp.zeros(off.shape, xw.dtype)
+    for j in range(RUN):
+        xg = jnp.where(off == j, g[j][:, None, :], xg)
+    return xg.reshape(-1, off.shape[2])
+
+
+def _gather_band(x: jax.Array, data_t: jax.Array,
+                 cols_t: jax.Array) -> jax.Array:
+    """``x[ICOL]`` of a band-major block ``(W, n_rows)``, ``W`` a multiple
+    of ``RUN``: by runs when every run of the block fits, else one
+    element per slot (:func:`_gather_x`).  The block's own traced columns
+    decide, on the device, so a streaming swap or a traced operator needs
+    no table kept beside it.  The window panel ``(RUN, n_cols)`` is built
+    outside the branch, so that the blocks of one operator share it."""
+    with jax.named_scope("gather_x"):
+        n = x.shape[0]
+        xp = jnp.pad(x, (0, RUN - 1))
+        xw = jnp.stack([xp[j:j + n] for j in range(RUN)])
+        cols, zero, base, fits = band_runs(data_t, cols_t)
+        return jax.lax.cond(fits,
+                            lambda: _gather_runs(xw, cols, zero, base),
+                            lambda: _gather_x(x, cols_t))
+
+
+def _bands(m):
+    """The ELL blocks of an ELL, SELL or hybrid operator."""
+    if isinstance(m, ELL):
+        yield m
+    elif isinstance(m, BucketedELL):
+        yield from m.buckets
+    else:
+        for b in getattr(m, "blocks", ()):
+            yield from _bands(b)
+
+
+def run_share(m) -> float:
+    """Of the ELL band slots of ``m`` (ELL, SELL or hybrid, on the host),
+    the share in blocks whose runs fit: the slots whose x the kernel
+    tier's SpMV gathers by runs.  0.0 when ``m`` has no ELL slots."""
+    served = total = 0
+    for b in _bands(m):
+        data, cols = np.asarray(b.data), np.asarray(b.cols)
+        if b.order == "row":
+            data, cols = data.T, cols.T
+        total += data.size
+        if data.size and band_runs(data, cols, np)[3]:
+            served += data.size
+    return served / total if total else 0.0
+
+
+# ---------------------------------------------------------------------------
 # ELL: band-major (width, n_rows) arrays
 # ---------------------------------------------------------------------------
 def _ell_geometry(n_rows: int, width: int,
@@ -127,8 +225,9 @@ def _ell_t_spmv(data_t: jax.Array, cols_t: jax.Array, x: jax.Array,
     br, bw = _ell_geometry(n_rows, width, tuning)
     data_t = _pad_to(_pad_to(data_t, 1, br), 0, bw)
     cols_t = _pad_to(_pad_to(cols_t, 1, br), 0, bw)
-    y = _ell.ell_spmv(data_t, _gather_x(x, cols_t), block_rows=br,
-                      block_w=bw, interpret=_interpret(interpret))
+    y = _ell.ell_spmv(data_t, _gather_band(x, data_t, cols_t),
+                      block_rows=br, block_w=bw,
+                      interpret=_interpret(interpret))
     return y[:n_rows].astype(jnp.result_type(data_t.dtype, x.dtype))
 
 

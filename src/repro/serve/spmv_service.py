@@ -74,6 +74,7 @@ from repro.core.plan import (BlockPlan, ExecutionPlan, PlanFingerprint,
                              blocks_by_format, rederive_slab_bounds)
 from repro.core.spmv import spmv as spmv_ref
 from repro.core.policy import MemoryPolicy
+from repro.kernels.ops import run_share
 from repro.partition import (HybridReport, build_hybrid, spmm_hybrid,
                              spmv_hybrid)
 from repro.serve import faults as _faults
@@ -169,6 +170,9 @@ class MatrixEntry:
     # (the ``service.host_bytes`` counter; 0 for a device-resident
     # operator); kept in step with ``matrix``
     host_bytes: int = field(default=0, init=False)
+    # share of the ELL band slots whose x the SpMV path gathers by column
+    # runs, from ``_place`` (None for a sharded operator, not placed there)
+    run_share: Optional[float] = None
 
     def __post_init__(self) -> None:
         self.host_bytes = host_nbytes(self.matrix)
@@ -338,16 +342,21 @@ class SpMVService:
             clock=self._now) for op in ("spmv", "spmm")}
 
     # -- registration --------------------------------------------------------
-    def _place(self, hyb: Any) -> Any:
-        """``hyb`` with every array leaf on the device, blocked until ready
-        (span ``service.place``, attribute ``bytes``).  The operator is
-        placed once, at registration and at each streaming swap, so no
-        served call copies it from host memory."""
+    def _place(self, hyb: Any) -> Tuple[Any, float]:
+        """``hyb`` with every array leaf on the device, blocked until ready,
+        and the share of its ELL band slots whose x the SpMV path gathers
+        by column runs (:func:`repro.kernels.ops.run_share`, read from the
+        host arrays): span ``service.place``, attributes ``bytes`` and
+        ``run_share``.  The operator is placed once, at registration and
+        at each streaming swap, so no served call copies it from host
+        memory."""
         with _obs.get().span("service.place") as sp:
+            share = run_share(hyb)
             placed = jax.block_until_ready(jax.device_put(hyb))
             sp.set(bytes=sum(leaf.nbytes for leaf in
-                             jax.tree_util.tree_leaves(placed)))
-        return placed
+                             jax.tree_util.tree_leaves(placed)),
+                   run_share=share)
+        return placed, share
 
     def _lint_registered_plan(self, key: str, plan: Any,
                               strict: bool) -> Any:
@@ -492,7 +501,7 @@ class SpMVService:
                 plan_matched = self._build_operator(
                     key, csr, plan, plan_matched, expected_iterations,
                     batch, build_kw, tel)
-            matrix = self._place(hyb)
+            matrix, share = self._place(hyb)
             fn = jax.jit(lambda m, x: spmv_hybrid(m, x, impls=impls))
             spmm_fn = jax.jit(
                 lambda m, x: spmm_hybrid(m, x, impls=spmm_impls))
@@ -508,7 +517,7 @@ class SpMVService:
                             spmm_fn=spmm_fn, t_build=t_build, t_csr=t_csr,
                             t_hybrid=t_hyb, builds=builds, tunings=tunings,
                             plan=entry_plan, from_plan=plan_matched,
-                            source=csr,
+                            source=csr, run_share=share,
                             host_matrix=hyb if streaming else None,
                             max_batch=(plan.batch if plan is not None
                                        and plan.batch > 1 else None))
@@ -762,9 +771,10 @@ class SpMVService:
                 perm=perm, blocks=(res.container,), row_offsets=(0,),
                 formats=(fmt,), shape=res.csr.shape, nnz=res.csr.nnz,
                 identity_perm=True)
-            placed = self._place(new_hyb)
+            placed, share = self._place(new_hyb)
             with entry.lock:
                 entry.matrix = placed
+                entry.run_share = share
                 entry.host_matrix = new_hyb
                 entry.host_bytes = host_nbytes(placed)
                 entry.source = res.csr
@@ -1148,6 +1158,7 @@ class SpMVService:
                 "formats": e.formats(),
                 "bytes": nbytes,
                 "device_bytes": nbytes - e.host_bytes,
+                "run_share": e.run_share,
                 "t_build_s": e.t_build,
                 "n_calls": e.n_calls,
                 "n_spmm_calls": e.n_spmm_calls,

@@ -20,6 +20,10 @@ BATCH = 32
 XENON2 = dict(n=157464, width=48)
 # xenon2's two widest SELL buckets as the hybrid build cuts them
 XENON2_SELL = ((2098, 48), (4638, 40))
+# the largest ELL-Row blocks of the solve cells' hybrids, (rows, width,
+# columns): xenon2's 25-wide block, torso1's 37-wide block and its
+# 4,959-wide block of heavy rows
+RUN_BLOCKS = ((15435, 25, 157464), (114801, 37, 116158), (857, 4959, 116158))
 # torso1 (Table 1): 116,158 rows, 8,516,500 nonzeros (8-padded)
 TORSO1 = dict(n=116158, nnz_pad=8516504)
 # a static slab bound of torso1's order at the default tiles
@@ -56,10 +60,10 @@ def _sds(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _ell(sh, n, width, order="row"):
+def _ell(sh, n, width, order="row", n_cols=None):
     shape = (n, width) if order == "row" else (width, n)
     return ELL(data=_sds(sh, shape), cols=_sds(sh, shape, jnp.int32),
-               shape=(n, n), nnz=n * width, order=order)
+               shape=(n, n_cols or n), nnz=n * width, order=order)
 
 
 def _sell(sh, n, buckets):
@@ -114,6 +118,17 @@ def test_sell_default_compiles(one_chip, op):
 def test_csr_default_compiles(one_chip, op):
     _compile(IMPLS["csr", op], _csr(one_chip, **TORSO1), one_chip, op,
              TileGeometry(slabs_per_block=TORSO1_SLABS))
+
+
+@pytest.mark.parametrize("n,width,n_cols", RUN_BLOCKS)
+def test_ell_run_gather_compiles(one_chip, n, width, n_cols):
+    """The SpMV path at the block shapes the solve cells run: the block's
+    columns choose, on the device, between the gather by 8-wide column
+    runs and the gather of one element per slot."""
+    hlo = _compile(IMPLS["ell", "spmv"],
+                   _ell(one_chip, n, width, n_cols=n_cols), one_chip,
+                   "spmv").as_text()
+    assert "conditional" in hlo
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,7 @@ from .common import print_rows, use_compile_cache
 
 
 SECTIONS = ("table1", "fig56", "fig7", "fig8", "hybrid", "spmm_batch",
-            "dstar", "moe", "kernels", "roofline", "obs", "guard",
-            "sharded", "stream")
+            "dstar", "kernels", "obs", "guard", "sharded", "stream")
 
 QUICK_SCALE = 0.02
 
@@ -91,8 +90,8 @@ def main() -> None:
             print(f"# wrote {path}", file=sys.stderr)
 
     from . import (fig56_speedup, fig7_overhead, fig8_graph, hybrid_blocks,
-                   kernels_bench, moe_dispatch, obs_overhead, roofline,
-                   sharded_spmv, spmm_batch, stream_updates, table1)
+                   kernels_bench, obs_overhead, sharded_spmv, spmm_batch,
+                   stream_updates, table1)
     scale_kw = {"scale": scale} if scale is not None else {}
     section("table1", table1.run, **scale_kw)
     section("fig56", fig56_speedup.run, **scale_kw)
@@ -101,9 +100,7 @@ def main() -> None:
     section("hybrid", hybrid_blocks.run, **scale_kw)
     section("spmm_batch", spmm_batch.run, **scale_kw)
     section("dstar", spmm_batch.dstar_sweep, **scale_kw)
-    section("moe", moe_dispatch.run)
     section("kernels", kernels_bench.run)
-    section("roofline", roofline.run)
     section("obs", obs_overhead.run, **scale_kw)
     section("stream", stream_updates.run, **scale_kw)
     section("guard", obs_overhead.run_guard, **scale_kw)

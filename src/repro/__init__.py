@@ -1,5 +1,5 @@
 """repro — auto-tuned run-time sparse-format transformation for SpMV
-(Katagiri & Sato) built out as a multi-pod JAX training/serving framework.
+(Katagiri & Sato) built out as a JAX/Pallas SpMV serving system.
 
 The public API is the plan pipeline (see ``repro.api`` and
 ``docs/plans.md``)::

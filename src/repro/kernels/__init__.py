@@ -3,4 +3,3 @@ jit wrappers (ops) and pure-jnp oracles (ref)."""
 from . import ops, ref
 from .ell_spmv import ell_spmv, ell_spmm
 from .csr_spmv import csr_spmm_t
-from .decode_attention import decode_attention_int8

@@ -260,7 +260,7 @@ def ell_spmm_raw(data: jax.Array, cols: jax.Array, x: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# differentiable ELL SpMV (core op used inside models)
+# differentiable ELL SpMV
 # ---------------------------------------------------------------------------
 @jax.custom_vjp
 def ell_spmv_ad(data: jax.Array, cols: jax.Array, x: jax.Array) -> jax.Array:

@@ -43,22 +43,3 @@ def sell_spmv_ref(perm: jax.Array, bucket_arrays, row_offsets, n_rows: int,
         y = y.at[perm[off:off + data.shape[0]]].set(yb)
     return y
 
-
-def decode_attention_int8_ref(q, k_q, k_s, v_q, v_s, key_pos, q_pos,
-                              window=None):
-    """Oracle for the fused int8-KV decode kernel: dequantize, then the
-    masked max/exp/sum attention (mirrors models.attention math)."""
-    kf = k_q.astype(jnp.float32) * k_s.astype(jnp.float32)[..., None]
-    vf = v_q.astype(jnp.float32) * v_s.astype(jnp.float32)[..., None]
-    Dh = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(Dh, jnp.float32))
-    s = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32) * scale, kf)
-    valid = (key_pos >= 0) & (key_pos <= q_pos[:, None])
-    if window is not None:
-        valid &= key_pos > (q_pos[:, None] - window)
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = p.sum(axis=-1, keepdims=True)
-    out = jnp.einsum("bkgs,bskd->bkgd", p / jnp.maximum(l, 1e-30), vf)
-    return out.astype(q.dtype)

@@ -1,12 +1,11 @@
 from . import faults
-from .engine import Request, ServeEngine
 from .faults import FaultRegistry, InjectedFault
 from .guard import CircuitBreaker, GuardedImpl, GuardError, guard_ladder
 from .spmv_service import (AdmissionError, EvictedError, MatrixEntry,
                            SpMVService)
 
 __all__ = [
-    "Request", "ServeEngine", "MatrixEntry", "SpMVService",
+    "MatrixEntry", "SpMVService",
     # fault tolerance (docs/robustness.md)
     "GuardedImpl", "CircuitBreaker", "GuardError", "guard_ladder",
     "AdmissionError", "EvictedError",

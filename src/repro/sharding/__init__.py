@@ -1,17 +1,5 @@
-"""Sharding tier: mesh-elastic parameter rules and the distributed
-SpMV/SpMM executor (``ShardedPlannedMatrix``, docs/sharding.md)."""
-from .rules import (ParamSpec, ShardingRules, RULES_1POD, RULES_2POD,
-                    RULES_SERVE, RULES_ZERO1, active_rules, axes_tree,
-                    eval_shape_params, init_params, logical_to_sharding,
-                    param_count, rules_for_mesh, stack_spec, use_rules,
-                    with_logical_constraint)
+"""Sharding tier: the distributed SpMV/SpMM executor
+(``ShardedPlannedMatrix``, docs/sharding.md)."""
 from .spmv import ShardedPlannedMatrix, build_sharded, shard_csr
 
-__all__ = [
-    "ParamSpec", "ShardingRules", "RULES_1POD", "RULES_2POD",
-    "RULES_SERVE", "RULES_ZERO1", "rules_for_mesh", "use_rules",
-    "active_rules", "axes_tree", "eval_shape_params", "init_params",
-    "logical_to_sharding", "param_count", "stack_spec",
-    "with_logical_constraint",
-    "ShardedPlannedMatrix", "build_sharded", "shard_csr",
-]
+__all__ = ["ShardedPlannedMatrix", "build_sharded", "shard_csr"]

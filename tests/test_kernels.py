@@ -137,67 +137,3 @@ def test_kernel_autotune_integration(rng):
     assert "ell_row" in db.d_star
     assert all("ell_row" in r.formats for r in db.records)
 
-
-# ---------------------------------------------------------------------------
-# fused int8-KV flash-decode attention kernel
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("B,S,KV,G,Dh,window", [
-    (2, 512, 2, 3, 64, None),     # one chunk exactly
-    (1, 1024, 4, 1, 128, None),   # multi-chunk
-    (3, 640, 2, 2, 32, 256),      # ragged chunks + sliding window
-    (2, 512, 1, 6, 64, 128),      # MQA grouping + window
-])
-def test_decode_attention_int8_kernel(rng, B, S, KV, G, Dh, window):
-    from repro.kernels.decode_attention import decode_attention_int8
-    q = jnp.asarray(rng.normal(size=(B, KV, G, Dh)).astype(np.float32))
-    k_q = jnp.asarray(rng.integers(-127, 128, (B, S, KV, Dh)), jnp.int8)
-    v_q = jnp.asarray(rng.integers(-127, 128, (B, S, KV, Dh)), jnp.int8)
-    k_s = jnp.asarray(rng.random((B, S, KV)).astype(np.float32) * 0.02)
-    v_s = jnp.asarray(rng.random((B, S, KV)).astype(np.float32) * 0.02)
-    lens = rng.integers(S // 2, S, size=B)
-    key_pos = jnp.asarray(
-        np.where(np.arange(S)[None, :] < lens[:, None],
-                 np.arange(S)[None, :], -1), jnp.int32)
-    q_pos = jnp.asarray(lens - 1, jnp.int32)
-
-    s_chunk = 512
-    pad = (-S) % s_chunk
-    if pad:
-        padz = lambda a, fill=0: jnp.pad(
-            a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2),
-            constant_values=fill)
-        k_qp, v_qp = padz(k_q), padz(v_q)
-        k_sp, v_sp = padz(k_s), padz(v_s)
-        kpp = padz(key_pos, fill=-1)
-    else:
-        k_qp, v_qp, k_sp, v_sp, kpp = k_q, v_q, k_s, v_s, key_pos
-
-    got = decode_attention_int8(q, k_qp, k_sp, v_qp, v_sp, kpp, q_pos,
-                                window=window, s_chunk=s_chunk,
-                                interpret=True)
-    want = ref.decode_attention_int8_ref(q, k_q, k_s, v_q, v_s, key_pos,
-                                         q_pos, window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_decode_attention_int8_matches_model_decode(rng):
-    """The kernel agrees with the model's quantized decode path end to end
-    (same quantizer, same masking semantics)."""
-    from repro.models.attention import _quantize_kv
-    from repro.kernels.decode_attention import decode_attention_int8
-    B, S, KV, G, Dh = 2, 512, 2, 2, 32
-    k = rng.normal(size=(B, S, KV, Dh)).astype(np.float32)
-    v = rng.normal(size=(B, S, KV, Dh)).astype(np.float32)
-    k_q, k_s = _quantize_kv(jnp.asarray(k))
-    v_q, v_s = _quantize_kv(jnp.asarray(v))
-    q = jnp.asarray(rng.normal(size=(B, KV, G, Dh)).astype(np.float32))
-    key_pos = jnp.broadcast_to(jnp.arange(S), (B, S)).astype(jnp.int32)
-    q_pos = jnp.asarray([S - 1, S // 2], jnp.int32)
-
-    got = decode_attention_int8(q, k_q, k_s, v_q, v_s, key_pos, q_pos,
-                                interpret=True)
-    want = ref.decode_attention_int8_ref(q, k_q, k_s, v_q, v_s, key_pos,
-                                         q_pos)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-4)

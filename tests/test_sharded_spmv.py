@@ -5,7 +5,6 @@ mode (which supports more shards than devices); the shard_map SPMD path
 is exercised in subprocesses under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
-import json
 import os
 import subprocess
 import sys
@@ -19,8 +18,7 @@ from repro import obs
 from repro.core.autotune import TuningDB
 from repro.core.kernel_tune import KernelTuner
 from repro.core.plan import (SHARDED_SCHEMA_VERSION, PlanError,
-                             PlanSchemaError, Planner, ShardedPlan,
-                             shard_boundaries)
+                             PlanSchemaError, Planner, ShardedPlan)
 from repro.core.transform import csr_from_dense
 from repro.obs import FakeClock, InMemorySink, Telemetry
 from repro.partition import partition_for_devices, slice_csr_cols
@@ -256,21 +254,6 @@ def test_sharded_telemetry_spans_and_gauge(problem, rng):
         obs.set_default(prev)
 
 
-# ---------------------------------------------------------------------------
-# sharding/rules public exports (the __all__ fix)
-# ---------------------------------------------------------------------------
-def test_rules_all_exports_complete():
-    from repro.sharding import rules
-    for name in ("RULES_SERVE", "RULES_ZERO1", "rules_for_mesh",
-                 "use_rules", "active_rules"):
-        assert name in rules.__all__, name
-        assert hasattr(rules, name), name
-    import repro.sharding as sh
-    for name in ("RULES_SERVE", "rules_for_mesh", "use_rules",
-                 "active_rules", "ShardedPlannedMatrix", "build_sharded"):
-        assert name in sh.__all__ and hasattr(sh, name), name
-
-
 def test_api_exports_sharding_surface():
     from repro import api
     for name in ("ShardedPlan", "ShardedPlannedMatrix", "build_sharded",
@@ -278,6 +261,10 @@ def test_api_exports_sharding_surface():
         assert name in api.__all__ and hasattr(api, name), name
     assert repro.ShardedPlan is ShardedPlan
     assert repro.ShardedPlannedMatrix is ShardedPlannedMatrix
+    import repro.sharding as sh
+    assert sh.__all__ == ["ShardedPlannedMatrix", "build_sharded",
+                          "shard_csr"]
+    assert all(hasattr(sh, name) for name in sh.__all__)
 
 
 # ---------------------------------------------------------------------------

@@ -9,14 +9,15 @@ from .strategies import (PARTITIONERS, partition_balanced_nnz,
                          partition_variance)
 from .hybrid import (BLOCK_FORMATS, BlockDecision, HybridMatrix,
                      HybridReport, build_hybrid, choose_block_format,
-                     host_csr_to_hybrid, slice_csr, slice_csr_cols,
-                     spmm_hybrid, spmv_hybrid, take_rows_csr)
+                     host_csr_to_hybrid, reassembly, slice_csr,
+                     slice_csr_cols, spmm_hybrid, spmv_hybrid,
+                     take_rows_csr)
 
 __all__ = [
     "PARTITIONERS", "partition_fixed", "partition_balanced_nnz",
     "partition_variance", "partition_for_devices",
     "BLOCK_FORMATS", "HybridMatrix", "BlockDecision", "HybridReport",
     "build_hybrid", "choose_block_format", "host_csr_to_hybrid",
-    "slice_csr", "slice_csr_cols", "take_rows_csr", "spmv_hybrid",
-    "spmm_hybrid",
+    "slice_csr", "slice_csr_cols", "take_rows_csr", "reassembly",
+    "spmv_hybrid", "spmm_hybrid",
 ]

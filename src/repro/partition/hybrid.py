@@ -23,6 +23,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import dispatch as _dispatch
 from repro.core.autotune import (MachineModel, TuningDB, decide_cost_model,
@@ -324,20 +325,33 @@ def _block_impl(fmt: str, op: str,
     return fn if fn is not None else _dispatch.get_impl(fmt, op)
 
 
+def reassembly(m: Any) -> str:
+    """How :func:`spmv_hybrid` puts the block outputs of ``m`` back in row
+    order: ``"sort"`` (one key-value sort of ``(perm, y)`` on ``perm``)
+    for a hybrid whose partition sorted the rows, ``"none"`` when its
+    outputs just concatenate: a hybrid that kept row order, or an
+    operator that is not a hybrid (a sharded one)."""
+    return ("sort" if isinstance(m, HybridMatrix) and not m.identity_perm
+            else "none")
+
+
 def spmv_hybrid(m: HybridMatrix, x: jax.Array,
                 impls: Optional[Dict[str, Callable]] = None) -> jax.Array:
     """y = A @ x: each block through its format's SpMV, then reassemble.
 
     ``impls`` maps format name -> callable(block, x) (e.g. the Pallas
     wrappers in ``kernels/ops.py``); formats not overridden resolve to the
-    reference tier of the ``core/dispatch`` registry."""
+    reference tier of the ``core/dispatch`` registry.  ``perm`` is a
+    permutation, so sorting ``(perm, y)`` on ``perm`` puts ``y[i]`` at row
+    ``perm[i]`` exactly as the scatter ``zeros.at[perm].set(y)`` does, and
+    on the TPU at a fraction of its cost: the scatter pays per index."""
     outs = [_block_impl(fmt, "spmv", impls)(b, x)
             for fmt, b in zip(m.formats, m.blocks)]
     y = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
-    if m.identity_perm:
+    if reassembly(m) == "none":
         return y
     with jax.named_scope("reassemble"):
-        return jnp.zeros(m.n_rows, y.dtype).at[jnp.asarray(m.perm)].set(y)
+        return lax.sort((jnp.asarray(m.perm), y), num_keys=1)[1]
 
 
 def spmm_hybrid(m: HybridMatrix, x: jax.Array,
@@ -364,4 +378,4 @@ _dispatch.register_impl("hybrid", "spmm", spmm_hybrid)
 __all__ = ["BLOCK_FORMATS", "HybridMatrix", "BlockDecision", "HybridReport",
            "take_rows_csr", "slice_csr", "slice_csr_cols",
            "choose_block_format", "build_hybrid", "host_csr_to_hybrid",
-           "spmv_hybrid", "spmm_hybrid"]
+           "reassembly", "spmv_hybrid", "spmm_hybrid"]

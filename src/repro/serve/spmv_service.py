@@ -75,8 +75,8 @@ from repro.core.plan import (BlockPlan, ExecutionPlan, PlanFingerprint,
 from repro.core.spmv import spmv as spmv_ref
 from repro.core.policy import MemoryPolicy
 from repro.kernels.ops import run_share
-from repro.partition import (HybridReport, build_hybrid, spmm_hybrid,
-                             spmv_hybrid)
+from repro.partition import (HybridReport, build_hybrid, reassembly,
+                             spmm_hybrid, spmv_hybrid)
 from repro.serve import faults as _faults
 from repro.serve.guard import CircuitBreaker, GuardedImpl, guard_ladder
 
@@ -346,16 +346,17 @@ class SpMVService:
         """``hyb`` with every array leaf on the device, blocked until ready,
         and the share of its ELL band slots whose x the SpMV path gathers
         by column runs (:func:`repro.kernels.ops.run_share`, read from the
-        host arrays): span ``service.place``, attributes ``bytes`` and
-        ``run_share``.  The operator is placed once, at registration and
-        at each streaming swap, so no served call copies it from host
-        memory."""
+        host arrays): span ``service.place``, attributes ``bytes``,
+        ``run_share`` and ``reassembly`` (how the SpMV path undoes the
+        row permutation, :func:`repro.partition.reassembly`).  The
+        operator is placed once, at registration and at each streaming
+        swap, so no served call copies it from host memory."""
         with _obs.get().span("service.place") as sp:
             share = run_share(hyb)
             placed = jax.block_until_ready(jax.device_put(hyb))
             sp.set(bytes=sum(leaf.nbytes for leaf in
                              jax.tree_util.tree_leaves(placed)),
-                   run_share=share)
+                   run_share=share, reassembly=reassembly(hyb))
         return placed, share
 
     def _lint_registered_plan(self, key: str, plan: Any,
@@ -1159,6 +1160,7 @@ class SpMVService:
                 "bytes": nbytes,
                 "device_bytes": nbytes - e.host_bytes,
                 "run_share": e.run_share,
+                "reassembly": reassembly(e.matrix),
                 "t_build_s": e.t_build,
                 "n_calls": e.n_calls,
                 "n_spmm_calls": e.n_spmm_calls,

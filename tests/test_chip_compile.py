@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.core.formats import CSR, ELL, BucketedELL
 from repro.core.kernel_tune import TileGeometry, candidate_geometries
 from repro.kernels import ops
+from repro.partition import HybridMatrix
 
 BATCH = 32
 # xenon2 (Table 1): 157,464 rows, band of at most 42 -> 48 slots
@@ -24,6 +25,9 @@ XENON2_SELL = ((2098, 48), (4638, 40))
 # columns): xenon2's 25-wide block, torso1's 37-wide block and its
 # 4,959-wide block of heavy rows
 RUN_BLOCKS = ((15435, 25, 157464), (114801, 37, 116158), (857, 4959, 116158))
+# the published row counts of the solve cells' configurations: xenon2,
+# torso1, chem_master1 (Table 1)
+SOLVE_ROWS = (157464, 116158, 40401)
 # torso1 (Table 1): 116,158 rows, 8,516,500 nonzeros (8-padded)
 TORSO1 = dict(n=116158, nnz_pad=8516504)
 # a static slab bound of torso1's order at the default tiles
@@ -129,6 +133,22 @@ def test_ell_run_gather_compiles(one_chip, n, width, n_cols):
                    _ell(one_chip, n, width, n_cols=n_cols), one_chip,
                    "spmv").as_text()
     assert "conditional" in hlo
+
+
+@pytest.mark.parametrize("n", SOLVE_ROWS)
+def test_hybrid_sort_reassembly_compiles(one_chip, n):
+    """A length-sorted hybrid's SpMV at a solve cell's row count: two
+    ELL-Row blocks, their outputs put back in row order by one key-value
+    sort on ``perm``, with no scatter left."""
+    cut = n // 3
+    hyb = HybridMatrix(
+        perm=_sds(one_chip, (n,), jnp.int32),
+        blocks=(_ell(one_chip, cut, 16, n_cols=n),
+                _ell(one_chip, n - cut, 8, n_cols=n)),
+        row_offsets=(0, cut), formats=("ell_row", "ell_row"), shape=(n, n),
+        nnz=cut * 16 + (n - cut) * 8)
+    hlo = _compile(ops.spmv_hybrid, hyb, one_chip, "spmv").as_text()
+    assert "sort(" in hlo and "scatter(" not in hlo
 
 
 # ---------------------------------------------------------------------------

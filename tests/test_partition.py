@@ -1,22 +1,28 @@
 """Partitioned hybrid-format SpMV: strategies, per-block decisions,
 HybridMatrix correctness vs the dense/CSR reference, format integration,
 and the serve path."""
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
+import repro.obs as obs
 from repro.core import (MatrixStats, csr_from_dense, memory_bytes,
                         offline_phase, spmv)
 from repro.core.formats import FORMAT_NAMES
+from repro.core.plan import Planner
 from repro.core.policy import MemoryPolicy
 from repro.core.suite import TABLE1, synthesize, synthesize_power_law
 from repro.core.transform import TRANSFORMS_HOST
-from repro.partition import (PARTITIONERS, build_hybrid, choose_block_format,
-                             host_csr_to_hybrid, partition_balanced_nnz,
-                             partition_fixed, partition_variance, slice_csr,
+from repro.partition import (BLOCK_FORMATS, PARTITIONERS, build_hybrid,
+                             choose_block_format, host_csr_to_hybrid,
+                             partition_balanced_nnz, partition_fixed,
+                             partition_variance, reassembly, slice_csr,
                              spmm_hybrid, spmv_hybrid, take_rows_csr)
 from repro.serve import SpMVService
+from repro.stream.delta import DeltaBatch
 
 
 def random_dense(rng, n_rows, n_cols, density):
@@ -142,6 +148,99 @@ def test_hybrid_kernel_path_matches(rng):
 
 
 # ---------------------------------------------------------------------------
+# reassembly: the key-value sort on perm equals the scatter it replaced
+# ---------------------------------------------------------------------------
+def _scatter_form(m, y):
+    """The reassembly ``spmv_hybrid`` ran before the sort."""
+    return jnp.zeros(m.n_rows, y.dtype).at[jnp.asarray(m.perm)].set(y)
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+def _sorted_hybrid(case, perm, seed=0):
+    """A length-sorted hybrid: 301 rows (a multiple of neither 8 nor 128)
+    in several blocks or in one, or 203 rows of which every 7th is empty;
+    ``perm`` "random" swaps its stable length-sort for a random one."""
+    rng = np.random.default_rng(seed)
+    n = 203 if case == "empty_rows" else 301
+    dense = random_dense(rng, n, n, 0.05)
+    if case == "empty_rows":
+        dense[::7] = 0.0
+    kw = ({"max_blocks": 1} if case == "single"
+          else {"max_blocks": 6, "min_rows": 16})
+    hyb, _ = build_hybrid(csr_from_dense(dense, pad=8), strategy="variance",
+                          **kw)
+    assert not hyb.identity_perm
+    assert (hyb.n_blocks == 1) == (case == "single")
+    if perm == "random":
+        hyb = dataclasses.replace(
+            hyb, perm=rng.permutation(n).astype(np.int32))
+    return hyb
+
+
+def _tier(name):
+    if name == "kernel":
+        from repro.kernels import ops
+        return lambda m, x: ops.spmv_hybrid(m, x, interpret=True)
+    return spmv_hybrid
+
+
+@pytest.mark.parametrize("tier", ["reference", "kernel"])
+@pytest.mark.parametrize("perm", ["length_sort", "random"])
+@pytest.mark.parametrize("case", ["ragged", "single", "empty_rows"])
+def test_sort_reassembly_equals_scatter_bitwise(case, perm, tier):
+    hyb = _sorted_hybrid(case, perm)
+    assert reassembly(hyb) == "sort"
+    x = np.random.default_rng(1).normal(size=hyb.n_cols).astype(np.float32)
+    x[[3, 50, 120]] = [np.nan, np.inf, -np.inf]
+    x = jnp.asarray(x)
+    fn = _tier(tier)
+    concat = fn(dataclasses.replace(hyb, identity_perm=True), x)
+    got = fn(hyb, x)
+    np.testing.assert_array_equal(_bits(got), _bits(_scatter_form(hyb, concat)))
+
+
+@pytest.mark.parametrize("perm", ["length_sort", "random"])
+@pytest.mark.parametrize("case", ["ragged", "single", "empty_rows"])
+def test_sort_reassembly_moves_special_values_in_place(case, perm):
+    """Block outputs holding NaN, +-inf and -0.0 land, unchanged, at row
+    ``perm[i]``: the sort moves values and computes nothing."""
+    hyb = _sorted_hybrid(case, perm, seed=2)
+    y = np.random.default_rng(3).normal(size=hyb.n_rows).astype(np.float32)
+    y[::5] = -0.0
+    y[1::11], y[2::13], y[3::17] = np.nan, np.inf, -np.inf
+    offs = {id(b): o for b, o in zip(hyb.blocks, hyb.row_offsets)}
+
+    def block_out(b, v):
+        return v[offs[id(b)]:offs[id(b)] + b.n_rows]
+
+    impls = {f: block_out for f in BLOCK_FORMATS + ("csr",)}
+    got = spmv_hybrid(hyb, jnp.asarray(y), impls=impls)
+    np.testing.assert_array_equal(_bits(got)[np.asarray(hyb.perm)], _bits(y))
+    np.testing.assert_array_equal(
+        _bits(got), _bits(_scatter_form(hyb, jnp.asarray(y))))
+
+
+def test_identity_perm_hybrid_bypasses_reassembly():
+    dense = random_dense(np.random.default_rng(4), 301, 301, 0.05)
+    hyb, _ = build_hybrid(csr_from_dense(dense, pad=8), strategy="variance",
+                          sort_rows=False, max_blocks=6, min_rows=16)
+    assert hyb.identity_perm and hyb.n_blocks > 1
+    assert reassembly(hyb) == "none"
+    x = jnp.asarray(np.random.default_rng(5).normal(size=301), jnp.float32)
+    txt = jax.jit(spmv_hybrid).lower(hyb, x).as_text(debug_info=True)
+    assert "reassemble" not in txt and "stablehlo.sort" not in txt
+    outs = [spmv(b, x) for b in hyb.blocks]
+    np.testing.assert_array_equal(_bits(spmv_hybrid(hyb, x)),
+                                  _bits(jnp.concatenate(outs)))
+    sorted_hyb = _sorted_hybrid("ragged", "length_sort")
+    txt = jax.jit(spmv_hybrid).lower(sorted_hyb, x).as_text(debug_info=True)
+    assert "reassemble" in txt and "stablehlo.sort" in txt
+
+
+# ---------------------------------------------------------------------------
 # acceptance: skewed matrix -> >= 2 distinct block formats, bounded memory
 # ---------------------------------------------------------------------------
 def test_skewed_matrix_gets_multiple_formats():
@@ -225,3 +324,35 @@ def test_spmv_service(rng):
     assert sum(st["formats"].values()) == st["n_blocks"]
     svc.evict("m0")
     assert "m0" not in svc.entries
+
+
+def test_reassembly_reported_at_placement(rng):
+    """``reassembly`` in ``stats()`` and on every ``service.place`` span:
+    "sort" for the default length-sorted hybrid, "none" for a plan that
+    keeps row order, also after a streaming swap, and for any operator
+    that is not a hybrid."""
+    dense = random_dense(rng, 200, 200, 0.05)
+    m = csr_from_dense(dense, pad=8)
+    sink = obs.InMemorySink()
+    tel = obs.enable(sink=sink)
+    try:
+        svc = SpMVService()
+        svc.register("sorted", m, measure_baseline=False)
+        svc.register("kept", m, measure_baseline=False, streaming=True,
+                     plan=Planner(tier="kernel").plan(m, fmt="sell"))
+        svc.apply_delta("kept", DeltaBatch(
+            n_cols=200, update_rows=np.array([5]),
+            update_cols=np.array([7]),
+            update_vals=np.array([2.5], np.float32)))
+    finally:
+        tel.sinks.remove(sink)
+        obs.disable()
+    st = svc.stats()
+    assert (st["sorted"]["reassembly"], st["kept"]["reassembly"]) == \
+        ("sort", "none")
+    assert [r["attrs"]["reassembly"] for r in sink.spans()
+            if r["name"] == "service.place"] == ["sort", "none", "none"]
+    assert reassembly(m) == "none"
+    x = rng.normal(size=200).astype(np.float32)
+    np.testing.assert_allclose(np.asarray(svc.spmv("sorted", jnp.asarray(x))),
+                               dense @ x, rtol=1e-4, atol=1e-4)
